@@ -46,6 +46,8 @@ def main(argv=None) -> int:
     p.add_argument("--quiet", action="store_true")
     args = p.parse_args(argv)
     keys = args.only.split(",") if args.only else list(MODULES)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     all_rows = []
     failures = 0

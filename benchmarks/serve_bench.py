@@ -1075,15 +1075,25 @@ def bench_sharded(label: str):
     mesh=None and on every ``SHARDED_MESHES`` shape; commits absolute
     tokens/sec per mesh plus the factor relative to the in-subprocess
     single-device serve.  See ``SHARDED_MESHES`` for why rel_x < 1 is the
-    expected shape on a CPU host."""
+    expected shape on a CPU host.
+
+    The child is pinned to the CPU.  On an accelerator this process already
+    holds the device, and a CPU fake-device number says nothing about it,
+    so the bench refuses to run there (``chip_smoke.py --four-chips``
+    drives the mesh engine on real chips)."""
     import json
     import os
     import subprocess
     import sys
 
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"bench_sharded fakes 8 CPU devices; on {jax.default_backend()} "
+            f"run chip_smoke.py --four-chips instead")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [repo, os.path.join(repo, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))

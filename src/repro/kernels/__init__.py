@@ -6,21 +6,13 @@ CPU-only host they run under the Pallas interpreter.
 
 Every entry point takes ``interpret=None`` and resolves it through
 ``resolve_interpret``: interpret only when the default JAX backend is CPU,
-compile for real on TPU/GPU.  Pass an explicit bool to override — unless
-``NLDPE_FORCE_INTERPRET`` is set in the environment (any value but "" or
-"0"), which forces the interpreter regardless, so CI can run the whole
-suite through the Pallas interpreter on any backend.
+compile for real on TPU/GPU.  Pass an explicit bool to override.
 """
-import os
-
 import jax
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """None -> interpret iff the default backend is CPU; bools pass through.
-    NLDPE_FORCE_INTERPRET=1 overrides everything to True (CI matrix job)."""
-    if os.environ.get("NLDPE_FORCE_INTERPRET", "0") not in ("", "0"):
-        return True
+    """None -> interpret iff the default backend is CPU; bools pass through."""
     if interpret is None:
         return jax.default_backend() == "cpu"
     return interpret

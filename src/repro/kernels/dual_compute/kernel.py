@@ -18,11 +18,9 @@ nonlinearity.  The two kernels here are the software analogue of that wiring
   the output tile persist.
 
 VMEM per grid step (defaults bm=bn=bk=bq=128, f32): fused VMM — x tile
-64 KB, four G tiles 256 KB, out 64 KB, thresholds <= 8 KB -> ~0.4 MB, plus
-a bounded (strip, bn, bits, rows) <= ~1 MB compare intermediate during the
-final-step ACAM decode (walked in 8-row strips, see _DECODE_STRIP);
-log-domain flash — q/k/v tiles 3*64 KB, out 64 KB, two (bq,) stats -> ~0.26
-MB.  Both well under the ~16 MB VMEM of a TPU core.
+64 KB, four G tiles 256 KB, out 64 KB -> ~0.4 MB, thresholds in SMEM;
+log-domain flash — q/k/v tiles 3*64 KB, out 64 KB, two (bq, 1) scratch
+stats -> ~0.26 MB.  Both well under the ~16 MB VMEM of a TPU core.
 """
 from __future__ import annotations
 
@@ -32,16 +30,16 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import resolve_interpret
 from ..acam_activation.kernel import acam_decode_tile
 
 _NEG_INF = float("-inf")
 
-# ACAM decode strip height: the compare intermediate is
-# (strip, bn, bits, rows) — 8*128*8*128 bool = 1 MB worst case — so the
-# final-step activation walks the (bm, bn) accumulator in strips instead of
-# broadcasting the full tile (which would be ~16x that and blow VMEM).
+# ACAM decode strip height: the decode carries one accumulator per bit
+# plane, so the final-step activation walks the (bm, bn) accumulator in
+# one-vreg (8, bn) strips to keep all planes in registers.
 _DECODE_STRIP = 8
 
 
@@ -66,12 +64,11 @@ def _fused_kernel(x_ref, gp_ref, gn_ref, rp_ref, rn_ref, inv_ref, lo_ref,
     @pl.when(kk == nk - 1)
     def _activate():
         # bit-line current drives the ACAM directly: no ADC, no HBM store
-        lo, hi = lo_ref[...], hi_ref[...]
         bm = o_ref.shape[0]
         for r0 in range(0, bm, _DECODE_STRIP):
             r1 = min(r0 + _DECODE_STRIP, bm)
             o_ref[r0:r1, :] = acam_decode_tile(
-                o_ref[r0:r1, :], lo, hi, bits, out_lo, out_step)
+                o_ref[r0:r1, :], lo_ref, hi_ref, bits, out_lo, out_step)
 
 
 @functools.partial(jax.jit, static_argnames=("res_gain", "bits", "out_lo",
@@ -92,15 +89,13 @@ def fused_crossbar_acam_kernel(x: jax.Array, g_pos: jax.Array,
     k2, n = g_pos.shape
     assert k == k2 and m % bm == 0 and n % bn == 0 and k % bk == 0
     g_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
-    table_spec = pl.BlockSpec(lo.shape, lambda i, j, kk: (0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)   # scalars + tables
     return pl.pallas_call(
         functools.partial(_fused_kernel, res_gain=res_gain, bits=bits,
                           out_lo=out_lo, out_step=out_step),
         grid=(m // bm, n // bn, k // bk),
         in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-                  g_spec, g_spec, g_spec, g_spec,
-                  pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
-                  table_spec, table_spec],
+                  g_spec, g_spec, g_spec, g_spec, smem, smem, smem],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=resolve_interpret(interpret),
@@ -121,6 +116,7 @@ def _quant_apply(x, lo: float, hi: float, levels_m1: float):
 def _ld_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, t_ref, *,
                      causal: bool, bq: int, bk: int, lq: int, lk: int,
                      bits: int, score_range: float):
+    # m_ref/t_ref: (bq, 1) VMEM scratch — running max and quantized-exp sum
     iq, it = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3) // 3
     phase, j = it // nk, it % nk
@@ -133,24 +129,32 @@ def _ld_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, t_ref, *,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         t_ref[...] = jnp.zeros_like(t_ref)
 
-    # scores over already-log-quantized reconstructions (DMMul_1, fused mode)
-    s = jnp.dot(q_ref[0, 0], k_ref[0, 0].T, preferred_element_type=jnp.float32)
-    q_pos = iq * bq + jax.lax.iota(jnp.int32, bq) + (lk - lq)
-    k_pos = j * bk + jax.lax.iota(jnp.int32, bk)
+    # scores over already-log-quantized reconstructions (DMMul_1, fused
+    # mode).  Every later step re-quantizes onto a log/exp grid, so a
+    # score error moves codes across grid boundaries: the MXU runs full
+    # f32 passes, as the f32 reference does
+    s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                            (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    q_pos = (iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+             + (lk - lq))
+    k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     if causal:
-        valid = q_pos[:, None] >= k_pos[None, :]
+        valid = q_pos >= k_pos
     else:
         valid = jnp.ones((bq, bk), bool)
     s = jnp.where(valid, s, _NEG_INF)
 
     @pl.when(phase == 0)
     def _max_pass():                                   # Fig 6b step 0 (WTA)
-        m_ref[0, 0] = jnp.maximum(m_ref[0, 0], jnp.max(s, axis=-1))
+        m_ref[...] = jnp.maximum(m_ref[...],
+                                 jnp.max(s, axis=-1, keepdims=True))
 
     def quantized_scores():
-        mx = m_ref[0, 0]
+        mx = m_ref[...]
         m_safe = jnp.where(jnp.isfinite(mx), mx, 0.0)
-        y = s - m_safe[:, None]
+        y = s - m_safe
         return _quant_apply(jnp.where(jnp.isfinite(y), y, -r), -r, 0.0,
                             levels_m1)
 
@@ -158,16 +162,17 @@ def _ld_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, t_ref, *,
     def _sum_pass():                                   # steps 1-2: exp + adders
         sq = _quant_apply(jnp.exp(quantized_scores()), 0.0, 1.0, levels_m1)
         sq = jnp.where(valid, sq, 0.0)                 # digital gating
-        t_ref[0, 0] += jnp.sum(sq, axis=-1)
+        t_ref[...] += jnp.sum(sq, axis=-1, keepdims=True)
 
     @pl.when(phase == 2)
     def _out_pass():                                   # steps 3-4 + DMMul_2
-        log_total = _quant_apply(jnp.log(jnp.maximum(t_ref[0, 0], 1e-9)),
+        log_total = _quant_apply(jnp.log(jnp.maximum(t_ref[...], 1e-9)),
                                  -r, math.log(lk + 1), levels_m1)
-        logp = quantized_scores() - log_total[:, None]
+        logp = quantized_scores() - log_total
         a = jnp.exp(_quant_apply(logp, -2.0 * r, 0.0, levels_m1))
         a = jnp.where(valid, a, 0.0)
         o_ref[0, 0] += jnp.dot(a, v_ref[0, 0],
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
@@ -188,20 +193,17 @@ def logdomain_flash_kernel(q_l: jax.Array, k_l: jax.Array, v_l: jax.Array,
     nk = lk // bk
     kv_spec = pl.BlockSpec((1, 1, bk, d),
                            lambda bb, hh, iq, it: (bb, hh // group, it % nk, 0))
-    stat_spec = pl.BlockSpec((1, 1, bq), lambda bb, hh, iq, it: (bb, hh, iq))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_ld_flash_kernel, causal=causal, bq=bq, bk=bk,
                           lq=lq, lk=lk, bits=bits, score_range=score_range),
         grid=(b, h, lq // bq, 3 * nk),
         in_specs=[pl.BlockSpec((1, 1, bq, d),
                                lambda bb, hh, iq, it: (bb, hh, iq, 0)),
                   kv_spec, kv_spec],
-        out_specs=[pl.BlockSpec((1, 1, bq, d),
-                                lambda bb, hh, iq, it: (bb, hh, iq, 0)),
-                   stat_spec, stat_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32),
-                   jax.ShapeDtypeStruct((b, h, lq), jnp.float32),
-                   jax.ShapeDtypeStruct((b, h, lq), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, bq, d),
+                               lambda bb, hh, iq, it: (bb, hh, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32)],
         interpret=resolve_interpret(interpret),
     )(q_l, k_l, v_l)
-    return out[0]

@@ -80,13 +80,15 @@ def fused_crossbar_acam(x: jax.Array, plan: SlicedWeights, table: ACAMTable,
 
 def fused_linear_acam(x: jax.Array, w: jax.Array, act: str, bits: int = 8,
                       in_domain: tuple[float, float] | None = None,
-                      interpret: bool | None = None) -> jax.Array:
+                      interpret: bool | None = None,
+                      use_ref: bool = False) -> jax.Array:
     """acam_act(x @ w) through an ideally-programmed A-SL crossbar, fused.
 
     x: (..., K) any leading shape; w: (K, N).  Programming is noise-free
     (W_eff == w exactly), jit-traceable (w_max stays a traced scalar), and
     happens per call — the simulation analogue of the deployed chip reading
-    its already-programmed cells.
+    its already-programmed cells.  ``use_ref`` runs the same programmed
+    cells through the two-step jnp oracle instead of the kernel.
     """
     from ...core.acam import get_table
 
@@ -99,7 +101,14 @@ def fused_linear_acam(x: jax.Array, w: jax.Array, act: str, bits: int = 8,
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]).astype(jnp.float32)
     cells = [plan.g_pos_main, plan.g_neg_main, plan.g_pos_res, plan.g_neg_res]
-    out = _pad_and_run(x2, cells, inv, table, DEFAULT.g_min, interpret)
+    if use_ref:
+        lo, hi = _thresholds(table)
+        out = fused_crossbar_acam_ref(x2, *cells, inv, lo, hi, table.bits,
+                                      float(table.out_spec.lo),
+                                      float(table.out_spec.step),
+                                      RESIDUAL_GAIN)
+    else:
+        out = _pad_and_run(x2, cells, inv, table, DEFAULT.g_min, interpret)
     return out.reshape(*shape[:-1], w.shape[1])
 
 

@@ -21,17 +21,21 @@ logical positions ``< lengths[b] + j`` (query ``j`` sits ``j`` positions
 past the base length), which makes the causal staircase across the
 in-flight chunk fall out of the same mask that handles partially-filled
 tail pages.  Online softmax carries running max/denominator across the
-page axis in revisited output buffers, exactly like
+page axis in VMEM scratch (``(G*q_len, 1)`` columns: a stats *output*
+block of ``(1, 1, G*q_len)`` would break the TPU rule that a block's last
+two dims are (8, 128)-aligned or whole), exactly like
 ``kernels/flash_attention``.
 
 Quantized pools (DESIGN.md §11): with ``kv_quant`` set the page tiles hold
 int8 codes and two extra scale operands ride the same block-table index
 map, so each grid step dequantizes ONE (ps, D) page in VMEM with the
 shared ``core.quantization.kv_decode`` formula — the full fp pool never
-exists anywhere.  Block-table entries outside ``[0, num_pages)`` are the
-unmapped-block sentinel: the index map clamps them (the DMA must stay in
-bounds) and the body masks the whole page out of the softmax, mirroring
-the write path's OOB-drop scatter.
+exists anywhere.  The scales ride as (P, Hkv, ps, 1) so their block's last
+two dims are whole and the (ps, 1) tile broadcasts over the page's lanes.
+Block-table entries outside ``[0, num_pages)`` are the unmapped-block
+sentinel: the index map clamps them (the DMA must stay in bounds) and the
+body masks the whole page out of the softmax, mirroring the write path's
+OOB-drop scatter.
 
 VMEM per step (ps=64, D=128, G=8, q_len=5, f32): k/v page tiles 32 KB
 each (8 KB as int8 codes + 256 B scales), q/out 20 KB, m/l tiny -> well
@@ -58,7 +62,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     if kv_quant is not None:
         ks_ref, vs_ref, o_ref, m_ref, l_ref = rest
     else:
-        o_ref, m_ref, l_ref = rest
+        o_ref, m_ref, l_ref = rest                 # m/l: (G*q_len, 1) scratch
     bb, i = pl.program_id(0), pl.program_id(2)
     nb = pl.num_programs(2)
 
@@ -70,13 +74,14 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     q = q_ref[0, 0] * scale                        # (G*q_len, d)
     if kv_quant is not None:                       # dequant ONE page in VMEM
-        k = kv_decode(k_ref[0, 0], ks_ref[0, 0], kv_quant)
-        v = kv_decode(v_ref[0, 0], vs_ref[0, 0], kv_quant)
+        k = kv_decode(k_ref[0, 0], None, kv_quant) * ks_ref[0, 0]
+        v = kv_decode(v_ref[0, 0], None, kv_quant) * vs_ref[0, 0]
     else:
         k = k_ref[0, 0]                            # (ps, d)
         v = v_ref[0, 0]
     gq = q.shape[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (G*q_len, ps)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (G*q_len, ps)
 
     # logical positions of this page; query row g*q_len + j attends to
     # positions < lengths[b] + j (TPU needs >= 2-d iota: broadcasted)
@@ -88,22 +93,22 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     ok = (pos < len_ref[bb] + qoff) & (blk >= 0) & (blk < num_pages)
     s = jnp.where(ok, s, _NEG_INF)
 
-    m_old = m_ref[0, 0]                            # (G*q_len,)
-    l_old = l_ref[0, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
+    m_old = m_ref[...]                             # (G*q_len, 1)
+    l_old = l_ref[...]
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe[:, None])               # masked s=-inf -> 0
+    p = jnp.exp(s - m_safe)                        # masked s=-inf -> 0
     corr = jnp.where(jnp.isfinite(m_old), jnp.exp(m_old - m_safe), 0.0)
-    l_new = l_old * corr + jnp.sum(p, axis=-1)
-    acc = o_ref[0, 0] * corr[:, None] + jnp.dot(
+    l_new = l_old * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc = o_ref[0, 0] * corr + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_new
+    m_ref[...] = m_new
+    l_ref[...] = l_new
 
     @pl.when(i == nb - 1)
     def _final():
         denom = jnp.where(l_new == 0.0, 1.0, l_new)
-        o_ref[0, 0] = acc / denom[:, None]
+        o_ref[0, 0] = acc / denom
 
     @pl.when(i != nb - 1)
     def _store():
@@ -145,29 +150,23 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
                 kv_spec, kv_spec]
     operands = [qg, k_pages, v_pages]
     if kv_quant is not None:
-        sc_spec = pl.BlockSpec(
-            (1, 1, ps), lambda bb, hh, i, bt, ln:
-            (jnp.clip(bt[bb, i], 0, num_pages - 1), hh, 0))
+        sc_spec = pl.BlockSpec((1, 1, ps, 1), page_idx)
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [k_scale[..., None], v_scale[..., None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, nb),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, 1, g * q_len, d),
-                                lambda bb, hh, i, bt, ln: (bb, hh, 0, 0)),
-                   pl.BlockSpec((1, 1, g * q_len),
-                                lambda bb, hh, i, bt, ln: (bb, hh, 0)),
-                   pl.BlockSpec((1, 1, g * q_len),
-                                lambda bb, hh, i, bt, ln: (bb, hh, 0))],
+        out_specs=pl.BlockSpec((1, 1, g * q_len, d),
+                               lambda bb, hh, i, bt, ln: (bb, hh, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((g * q_len, 1), jnp.float32),
+                        pltpu.VMEM((g * q_len, 1), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, ps=ps, q_len=q_len,
                           num_pages=num_pages, kv_quant=kv_quant),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, hkv, g * q_len, d), jnp.float32),
-                   jax.ShapeDtypeStruct((b, hkv, g * q_len), jnp.float32),
-                   jax.ShapeDtypeStruct((b, hkv, g * q_len), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g * q_len, d), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(block_tables, lengths, *operands)
-    return out[0].reshape(b, hkv, g, q_len, d).reshape(b, hq, q_len, d)
+    return out.reshape(b, hkv, g, q_len, d).reshape(b, hq, q_len, d)

@@ -97,7 +97,6 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
     boundary).  Any non-divisible axis falls back to replication — never
     an error.
     """
-    from ...parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     if kv_quant is None and k_scale is not None:
@@ -120,7 +119,7 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
                                    v_scale=vs_, kv_quant=kv_quant,
                                    interpret=interpret, use_ref=use_ref)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(q_spec, kv_spec, kv_spec, P(data_ax, None),
                       P(data_ax), sc_spec, sc_spec),
@@ -131,7 +130,7 @@ def paged_attention_sharded(q: jax.Array, k_pages: jax.Array,
         return paged_attention(q_, kp_, vp_, bt_, ln_,
                                interpret=interpret, use_ref=use_ref)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P(data_ax, None), P(data_ax)),
         out_specs=q_spec, check_vma=False,

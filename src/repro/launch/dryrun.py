@@ -177,8 +177,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):          # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_report = {k: getattr(mem, k) for k in

@@ -165,7 +165,7 @@ class ServeEngine:
     def __init__(self, cfg, params, *, max_slots: int, max_len: int,
                  nldpe: NLDPEConfig = OFF, prefill_chunk: int = 16,
                  decode_block: int = 4, eos_id: int = -1,
-                 batch_groups: int = 1, dtype=jnp.float32,
+                 batch_groups: int = 1,
                  kv_quant: str | None = None,
                  mesh=None, rules=None,
                  telemetry: "Telemetry | bool | None" = None,
@@ -211,7 +211,9 @@ class ServeEngine:
         self.decode_block = decode_block
         self.eos_id = eos_id
         self.batch_groups = batch_groups
-        self.dtype = dtype
+        # the KV cache is stored in the weights' dtype: bf16 weights serve
+        # from a bf16 cache, f32 weights (the CPU test models) from f32
+        self.dtype = params["embed"]["table"].dtype
 
         s = max_slots
         self.cache = self._place_cache(self._init_cache())
@@ -256,10 +258,13 @@ class ServeEngine:
         if self.telemetry is not None:
             self.metrics.register_group("latency", self.telemetry.summary)
 
+        # every jit below takes the params as an argument: a closed-over
+        # array is embedded in the program as a constant, which at published
+        # widths copies the weights into each executable
         self._chunk_fn = jax.jit(self._ctx(self._build_chunk_fn()),
-                                 donate_argnums=(0,))
+                                 donate_argnums=(1,))
         self._decode_fn = jax.jit(self._ctx(self._build_decode_fn()),
-                                  donate_argnums=(0, 1, 2, 3, 4))
+                                  donate_argnums=(1, 2, 3, 4, 5))
         self._last_fn = jax.jit(self._ctx(_merge_last), donate_argnums=(0,))
         # first-token sampler, fixed (max_slots, V) shape so it compiles once
         self._sample_fn = jax.jit(self._ctx(
@@ -371,7 +376,7 @@ class ServeEngine:
         cfg, nldpe, groups = self.cfg, self.nldpe, self.batch_groups
         c = self.prefill_chunk
 
-        def chunk(cache, tokens, base_pos, mask, limit):
+        def chunk(params, cache, tokens, base_pos, mask, limit):
             """One (max_slots, prefill_chunk) prefill chunk, shared offsets,
             per-slot write masks.
 
@@ -384,7 +389,7 @@ class ServeEngine:
             """
             cache = ServeEngine._clip_pos(cache, mask, base_pos)
             positions = base_pos + jnp.arange(c, dtype=jnp.int32)
-            logits, cache = lm.forward(self.params, tokens, cfg, mode="chunk",
+            logits, cache = lm.forward(params, tokens, cfg, mode="chunk",
                                        cache=cache, positions=positions,
                                        nldpe=nldpe, batch_groups=groups,
                                        write_mask=mask)
@@ -414,15 +419,15 @@ class ServeEngine:
         chunk = self._build_chunk_fn()
         c, s, v = self.prefill_chunk, self.max_slots, self.cfg.vocab_size
 
-        def bucket(cache, tokens, admit, reuse, plen, ci, col):
+        def bucket(params, cache, tokens, admit, reuse, plen, ci, col):
             suffix = plen - reuse
             last = jnp.zeros((s, v), jnp.float32)
             for i in range(n):
                 mask = admit & (i * c < suffix)
                 limit = jnp.minimum(plen, reuse + (i + 1) * c)
                 lg, cache = chunk(
-                    cache, jax.lax.slice_in_dim(tokens, i * c, (i + 1) * c,
-                                                axis=1),
+                    params, cache,
+                    jax.lax.slice_in_dim(tokens, i * c, (i + 1) * c, axis=1),
                     self._chunk_base(reuse, i), mask, limit)
                 last = _merge_last(last, lg, admit & (ci == i), col)
             return last, cache
@@ -454,18 +459,19 @@ class ServeEngine:
             if not sizes or sizes[-1] != n_max:
                 sizes.append(n_max)
         self._bucket_sizes = sizes
-        cache_avals = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.cache)
+        param_avals, cache_avals = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+            (self.params, self.cache))
         vec = jax.ShapeDtypeStruct((s,), jnp.int32)
         adm = jax.ShapeDtypeStruct((s,), jnp.bool_)
         self.aot_prefill = self.mesh is None
         for n in sizes:
             fn = jax.jit(self._ctx(self._build_bucket_fn(n)),
-                         donate_argnums=(0,))
+                         donate_argnums=(1,))
             if self.aot_prefill:
                 toks = jax.ShapeDtypeStruct((s, n * c), jnp.int32)
-                fn = fn.lower(cache_avals, toks, adm, vec, vec, vec,
-                              vec).compile()
+                fn = fn.lower(param_avals, cache_avals, toks, adm, vec, vec,
+                              vec, vec).compile()
             self._bucket_fns[n] = fn
 
     def _prefill_chunks(self, admit, plen_np, reuse_np, tokens,
@@ -489,7 +495,7 @@ class ServeEngine:
                 pad[:, :tokens.shape[1]] = tokens
             self.prefill_pad_chunks += nb - n_chunks
             last, self.cache = self._bucket_fns[nb](
-                self.cache, jnp.asarray(pad), jnp.asarray(admit),
+                self.params, self.cache, jnp.asarray(pad), jnp.asarray(admit),
                 jnp.asarray(reuse_np), jnp.asarray(plen_np),
                 jnp.asarray(ci_np), jnp.asarray(col_np))
             return last, nb
@@ -499,7 +505,8 @@ class ServeEngine:
             mask = jnp.asarray(admit & (i * c < suffix))
             limit = np.minimum(plen_np, reuse_np + (i + 1) * c)
             lg, self.cache = self._chunk_fn(
-                self.cache, jnp.asarray(tokens[:, i * c:(i + 1) * c]),
+                self.params, self.cache,
+                jnp.asarray(tokens[:, i * c:(i + 1) * c]),
                 self._chunk_base(reuse_np, i), mask,
                 jnp.asarray(limit.astype(np.int32)))
             last = self._last_fn(last, lg,
@@ -520,11 +527,12 @@ class ServeEngine:
         cfg, nldpe, groups = self.cfg, self.nldpe, self.batch_groups
         eos, block = self.eos_id, self.decode_block
 
-        def decode(cache, tok, pos, active, gen_left, temp, topk, keys):
+        def decode(params, cache, tok, pos, active, gen_left, temp, topk,
+                   keys):
             def step(carry, _):
                 cache, tok, pos, active, gen_left = carry
                 logits, cache = lm.decode_step(
-                    self.params, cfg, tok, pos, cache, nldpe=nldpe,
+                    params, cfg, tok, pos, cache, nldpe=nldpe,
                     batch_groups=groups, write_mask=active)
                 nxt = sample_tokens(logits, step_keys(keys, pos + 1),
                                     temp, topk)
@@ -807,9 +815,9 @@ class ServeEngine:
             tel.tick_boundary(self.tick)
             t0 = tel.phases.now()
         (self.cache, self._tok, self._pos, self._active, self._gen_left,
-         emits) = self._decode_fn(self.cache, self._tok, self._pos,
-                                  self._active, self._gen_left, self._temp,
-                                  self._topk, self._keys)
+         emits) = self._decode_fn(self.params, self.cache, self._tok,
+                                  self._pos, self._active, self._gen_left,
+                                  self._temp, self._topk, self._keys)
         self.tick += self.decode_block
         active = self._snap_fn(self._active)
         if tel is None:
@@ -970,7 +978,7 @@ class PagedServeEngine(ServeEngine):
     def __init__(self, cfg, params, *, max_slots: int, max_len: int,
                  nldpe: NLDPEConfig = OFF, prefill_chunk: int = 16,
                  decode_block: int = 4, eos_id: int = -1,
-                 batch_groups: int = 1, dtype=jnp.float32,
+                 batch_groups: int = 1,
                  page_size: int = 16, num_pages: int | None = None,
                  host_cache_pages: int = 0,
                  spec_k: int = 0, spec_draft: NLDPEConfig | None = None,
@@ -1022,7 +1030,7 @@ class PagedServeEngine(ServeEngine):
         super().__init__(cfg, params, max_slots=max_slots, max_len=max_len,
                          nldpe=nldpe, prefill_chunk=prefill_chunk,
                          decode_block=decode_block, eos_id=eos_id,
-                         batch_groups=batch_groups, dtype=dtype,
+                         batch_groups=batch_groups,
                          kv_quant=kv_quant, mesh=mesh, rules=rules,
                          telemetry=telemetry, prefill_buckets=prefill_buckets)
         self._setup_fn = jax.jit(self._ctx(self._build_setup_fn()),
@@ -1225,9 +1233,9 @@ class PagedServeEngine(ServeEngine):
                 donate_argnums=(1,))    # the cache — never the weights
             verify = jax.jit(
                 self._ctx(build_verify_fn(
-                    self.cfg, self.params, spec_k=k, nldpe=self.nldpe,
+                    self.cfg, spec_k=k, nldpe=self.nldpe,
                     batch_groups=self.batch_groups, eos_id=self.eos_id)),
-                donate_argnums=(0, 1, 2, 3, 4))
+                donate_argnums=(1, 2, 3, 4, 5))
             fns = (draft, verify)
             self._spec_fn_cache[k] = fns
         return fns
@@ -1327,7 +1335,8 @@ class PagedServeEngine(ServeEngine):
         t1 = time.perf_counter()
         (self.cache, self._tok, self._pos, self._active, self._gen_left,
          emits, accepted) = verify_fn(
-            self.cache, self._tok, self._pos, self._active, self._gen_left,
+            self.params, self.cache, self._tok, self._pos, self._active,
+            self._gen_left,
             self._temp, self._topk, self._keys, drafts, q_probs)
         self.tick += 1
         self._spec_steps += 1
@@ -1365,14 +1374,14 @@ class PagedServeEngine(ServeEngine):
         cfg, nldpe, groups = self.cfg, self.nldpe, self.batch_groups
         c = self.prefill_chunk
 
-        def chunk(cache, tokens, base_pos, mask, limit):
+        def chunk(params, cache, tokens, base_pos, mask, limit):
             """One (max_slots, prefill_chunk) suffix-prefill chunk at
             **per-slot** base positions: prefix hits shift each slot's
             suffix independently, so ``base_pos``/``limit`` are (S,)
             vectors instead of the slotted engine's shared scalars."""
             cache = ServeEngine._clip_pos(cache, mask, base_pos)
             positions = base_pos[:, None] + jnp.arange(c, dtype=jnp.int32)
-            logits, cache = lm.forward(self.params, tokens, cfg, mode="chunk",
+            logits, cache = lm.forward(params, tokens, cfg, mode="chunk",
                                        cache=cache, positions=positions,
                                        nldpe=nldpe, batch_groups=groups,
                                        write_mask=mask)

@@ -9,18 +9,22 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary mesh for tests/elastic configs (device-count permitting)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests/elastic configs (device-count permitting).
+
+    Every axis is ``Auto``: the model places data with logical-axis
+    ``with_sharding_constraint`` calls (parallel/context.py), which only
+    accept Auto axes (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def serve_mesh(dp: int, tp: int):
@@ -35,7 +39,7 @@ def serve_mesh(dp: int, tp: int):
             f"{len(jax.devices())}; set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={dp * tp} "
             f"before jax initializes (CPU), or shrink the mesh")
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return make_mesh((dp, tp), ("data", "model"))
 
 
 def single_device_mesh():

@@ -32,6 +32,8 @@ lower collective volume).
 
 The CLI driver below runs a reduced config end-to-end (prefill a batch of
 prompts, then decode), optionally through the NL-DPE numerics mode.
+``--published-width`` serves the architecture at its published config
+with bf16 weights and KV cache instead — the size to run on a TPU.
 """
 from __future__ import annotations
 
@@ -249,9 +251,13 @@ def _validate_args(p, args) -> None:
                 "analog draft path)")
 
 
-def run(argv=None):
+def _parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen2_5_3b")
+    p.add_argument("--published-width", action="store_true",
+                   help="the architecture's published config with bf16 "
+                        "weights and KV cache (default: the reduced CPU "
+                        "demo config in f32)")
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--gen-len", type=int, default=32)
@@ -355,8 +361,66 @@ def run(argv=None):
                         "length buckets (DESIGN.md §14); tokens are "
                         "bit-identical to the tick loop")
     p.add_argument("--seed", type=int, default=0)
+    return p
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    p = _parser()
     args = p.parse_args(argv)
     _validate_args(p, args)
+    return args
+
+
+def load_model(args):
+    """(cfg, params, nldpe) for the parsed CLI: the reduced config in f32,
+    or with ``--published-width`` the published config in bf16.  The
+    weights are random, drawn from ``--seed``."""
+    from ..nn.module import param_dtype
+    cfg = get_config(args.arch, reduced=not args.published_width)
+    nldpe = NLDPEConfig(enabled=args.nldpe or args.fused,
+                        fused_dual_compute=args.fused)
+    dtype = jnp.bfloat16 if args.published_width else jnp.float32
+    with param_dtype(dtype):
+        params = jax.jit(lambda k: lm.init_params(k, cfg))(
+            jax.random.key(args.seed))
+    return cfg, params, nldpe
+
+
+def build_paged_engine(cfg, params, nldpe, args, *, max_len: int,
+                       mesh=None, telemetry=None):
+    """The ``--paged`` engine for the parsed CLI (the demo and
+    ``chip_smoke.py`` both build it here)."""
+    from .engine import PagedServeEngine
+    spec_draft = NLDPEConfig(enabled=args.spec_full_analog)
+    drift = fidelity = None
+    if args.drift is not None or args.fault_rate:
+        from ..core.drift import DriftModel
+        from .fidelity import DriftInjection, FidelityPolicy
+        drift = DriftInjection(
+            model=DriftModel(nu=args.drift or 0.0, t0=args.drift_dt,
+                             fault_rate=args.fault_rate),
+            seed=args.seed, dt_step=args.drift_dt,
+            reprogram_s=10 * args.drift_dt)
+        # short demo traces: decide every 4 spec ticks so the ladder
+        # is visible within a few dozen requests
+        fidelity = FidelityPolicy(window=4)
+    return PagedServeEngine(cfg, params, max_slots=args.slots,
+                            max_len=max_len, nldpe=nldpe,
+                            page_size=args.page_size,
+                            num_pages=args.num_pages,
+                            host_cache_pages=args.host_cache_pages,
+                            spec_k=args.spec, spec_draft=spec_draft,
+                            drift=drift, fidelity=fidelity,
+                            kv_quant=args.kv_quant,
+                            mesh=mesh, rules=args.mesh_rules,
+                            telemetry=telemetry,
+                            prefill_buckets=args.async_serve or None)
+
+
+def run(argv=None):
+    from ..runtime.compile_cache import enable_compile_cache
+    args = parse_args(argv)
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:
@@ -370,18 +434,13 @@ def run(argv=None):
         tel = Telemetry(profile_ticks=args.profile_ticks,
                         profile_dir=args.profile_dir)
 
-    cfg = get_config(args.arch, reduced=True)
-    nldpe = NLDPEConfig(enabled=args.nldpe or args.fused,
-                        fused_dual_compute=args.fused)
+    cfg, params, nldpe = load_model(args)
     key = jax.random.key(args.seed)
-    from ..nn.module import param_dtype
-    with param_dtype(jnp.float32):
-        params = lm.init_params(key, cfg)
 
     if args.paged:
         import numpy as np
 
-        from .engine import PagedServeEngine, Request
+        from .engine import Request
         rng = np.random.default_rng(args.seed)
         sys_len = min(args.system_prompt_len, args.prompt_len)
         max_len = args.prompt_len + args.gen_len
@@ -398,33 +457,9 @@ def run(argv=None):
                                   and i % args.priority == args.priority - 1
                                   else 0))
                 for i in range(args.requests)]
-        spec_draft = (NLDPEConfig(enabled=True) if args.spec_full_analog
-                      else NLDPEConfig(enabled=False))
-        drift = None
-        if args.drift is not None or args.fault_rate:
-            from ..core.drift import DriftModel
-            from .fidelity import DriftInjection, FidelityPolicy
-            drift = DriftInjection(
-                model=DriftModel(nu=args.drift or 0.0, t0=args.drift_dt,
-                                 fault_rate=args.fault_rate),
-                seed=args.seed, dt_step=args.drift_dt,
-                reprogram_s=10 * args.drift_dt)
-            # short demo traces: decide every 4 spec ticks so the ladder
-            # is visible within a few dozen requests
-            fidelity = FidelityPolicy(window=4)
-        eng = PagedServeEngine(cfg, params, max_slots=args.slots,
-                               max_len=max_len, nldpe=nldpe,
-                               page_size=args.page_size,
-                               num_pages=args.num_pages,
-                               host_cache_pages=args.host_cache_pages,
-                               spec_k=args.spec,
-                               spec_draft=spec_draft, drift=drift,
-                               fidelity=(fidelity if drift is not None
-                                         else None),
-                               kv_quant=args.kv_quant,
-                               mesh=mesh, rules=args.mesh_rules,
-                               telemetry=tel,
-                               prefill_buckets=args.async_serve or None)
+        eng = build_paged_engine(cfg, params, nldpe, args, max_len=max_len,
+                                 mesh=mesh, telemetry=tel)
+        drift = eng.drift
         runner = _wrap_async(eng, args)
         t0 = time.time()
         comps = runner.run(reqs)
@@ -505,7 +540,8 @@ def run(argv=None):
                   f"tokens={c.tokens[:8]}")
         return comps
     max_len = args.prompt_len + args.gen_len
-    cache = lm.init_model_cache(cfg, args.batch, max_len, dtype=jnp.float32)
+    cache = lm.init_model_cache(cfg, args.batch, max_len,
+                                dtype=params["embed"]["table"].dtype)
     prompts = jax.random.randint(key, (args.batch, args.prompt_len), 0,
                                  cfg.vocab_size)
 

@@ -127,11 +127,15 @@ def quantize_draft_params(params, logdomain: LogDomainConfig | None = None):
     would actually hold once programmed.  Computed once at engine init and
     cached on device; the drafter then runs the *same* forward as the
     target, just with conductance-faithful weights (plus whatever analog
-    numerics its NLDPEConfig enables)."""
+    numerics its NLDPEConfig enables).  The copy keeps each parameter's
+    dtype, so bf16 serving holds two bf16 weight sets, not bf16 + f32."""
     if logdomain is None:
         logdomain = LogDomainConfig()
-    return jax.tree.map(
-        lambda w: log_quantize(w.astype(jnp.float32), logdomain), params)
+    # one fused program per leaf: eager ops would hold several f32
+    # temporaries of the largest weight stack at once
+    program = jax.jit(lambda w: log_quantize(w.astype(jnp.float32),
+                                             logdomain).astype(w.dtype))
+    return jax.tree.map(program, params)
 
 
 # ---------------------------------------------------------------------------
@@ -222,7 +226,7 @@ def build_draft_scan_fn(cfg, *, spec_k: int,
     return draft_scan
 
 
-def build_verify_fn(cfg, params, *, spec_k: int, nldpe: NLDPEConfig = OFF,
+def build_verify_fn(cfg, *, spec_k: int, nldpe: NLDPEConfig = OFF,
                     batch_groups: int = 1, eos_id: int = -1):
     """The digital half of one speculative step, one jit:
 
@@ -242,8 +246,8 @@ def build_verify_fn(cfg, params, *, spec_k: int, nldpe: NLDPEConfig = OFF,
     """
     k = spec_k
 
-    def verify_step(cache, tok, pos, active, gen_left, temp, topk, keys,
-                    drafts, q_probs):
+    def verify_step(params, cache, tok, pos, active, gen_left, temp, topk,
+                    keys, drafts, q_probs):
         s = tok.shape[0]
         # exact verify: one chunk over [tok, d_1..d_k] at [pos, pos+k] —
         # overwrites every provisional draft write with exact-digital K/V

@@ -42,9 +42,13 @@ def embedding_apply(p, tokens: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
 
 
 def unembed_apply(p, x: jax.Array) -> jax.Array:
-    """Tied readout: logits = x @ E^T (f32 for a stable softmax-xent)."""
-    return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
-                      p["table"].astype(jnp.float32))
+    """Tied readout: logits = x @ E^T, accumulated and returned in f32 (for
+    a stable softmax-xent).  The table is read in its own dtype: casting a
+    bf16 vocabulary table to f32 would materialize a second, twice-as-large
+    copy of it on every call."""
+    dt = jnp.promote_types(x.dtype, p["table"].dtype)
+    return jnp.einsum("...d,vd->...v", x.astype(dt), p["table"].astype(dt),
+                      preferred_element_type=jnp.float32)
 
 
 # -- norms --------------------------------------------------------------------

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.compat import shard_map
 
 from .moe import MoESpec
 
@@ -115,7 +114,7 @@ def moe_apply_ep(p, x: jax.Array, s: MoESpec, mesh, *,
     in_p = {"router": P(), "gate": P(model_axis), "up": P(model_axis),
             "down": P(model_axis)}
     x_spec = P(data_axes if len(data_axes) > 1 else data_axes[0], None, None)
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(in_p, x_spec), out_specs=x_spec,
                        check_vma=False)
     return fn(p, x)

@@ -488,9 +488,9 @@ class TickProfiler:
     The trace starts at the first tick boundary after attach and stops N
     boundaries later; the resulting directory is loadable in perfetto (or
     TensorBoard's profile plugin).  jax is imported lazily so the rest of
-    the telemetry stack stays importable as a pure host-side module, and
-    profiler failures degrade to a no-op (some builds lack profiler deps)
-    rather than taking serving down.
+    the telemetry stack stays importable as a pure host-side module.  A
+    profiler that fails to start or stop raises: a run that asked for a
+    trace must not end without one.
     """
 
     def __init__(self, logdir: str | None, n_ticks: int):
@@ -506,12 +506,8 @@ class TickProfiler:
         if self.done:
             return
         if not self.active:
-            try:
-                import jax
-                jax.profiler.start_trace(self.logdir)
-            except Exception:                # missing profiler deps: no-op
-                self.done = True
-                return
+            import jax
+            jax.profiler.start_trace(self.logdir)
             self.active = True
             return
         self._remaining -= 1
@@ -520,10 +516,7 @@ class TickProfiler:
 
     def stop(self) -> None:
         if self.active:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            import jax
             self.active = False
+            jax.profiler.stop_trace()
         self.done = True

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -68,7 +67,7 @@ def cp_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         return out.reshape(b, hq, 1, d).astype(q_l.dtype)
 
     seq_spec = P(None, None, name, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), seq_spec, seq_spec, P(name), P()),
         out_specs=P(), check_vma=False)

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
@@ -71,7 +70,7 @@ def pipeline_forward(stage_params, x_micro, body_fn, mesh, axis: str = "pod"):
         return outs
 
     specs_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
+    fn = jax.shard_map(per_stage, mesh=mesh,
                        in_specs=(specs_p, P()), out_specs=P(axis),
                        check_vma=False)
     outs = fn(stage_params, x_micro)

@@ -18,6 +18,7 @@ def _run(script: str, devices: int = 8, timeout: int = 600):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
                          capture_output=True, text=True, env=env,
                          timeout=timeout)
@@ -31,6 +32,7 @@ def test_small_mesh_dryrun_lowers_with_collectives():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         from repro.nn.module import param_dtype, spec_mode
         from repro.optim import adamw
@@ -39,7 +41,7 @@ def test_small_mesh_dryrun_lowers_with_collectives():
         from repro.launch.train import build_train_step
         from repro.utils.hlo import collective_summary
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = rules_for("train", False)
         cfg = get_config("qwen2_7b", reduced=True)
         key = jax.random.key(0)
@@ -61,8 +63,6 @@ def test_small_mesh_dryrun_lowers_with_collectives():
             lowered = step.lower(shapes, opt_shapes, batch)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):      # jax 0.4.x: one dict per device
-            cost = cost[0] if cost else {}
         coll = collective_summary(compiled.as_text(), 8)
         print("FLOPS", cost.get("flops", 0.0))
         print("COLL", coll["total_wire_bytes_per_device"])
@@ -79,6 +79,7 @@ def test_small_mesh_execute_train_step():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import lm
         from repro.nn.module import param_dtype
         from repro.optim import adamw
@@ -86,7 +87,7 @@ def test_small_mesh_execute_train_step():
         from repro.parallel.sharding import rules_for
         from repro.launch.train import build_train_step
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = rules_for("train", False)
         cfg = get_config("qwen2_5_3b", reduced=True)
         with param_dtype(jnp.float32):
@@ -112,9 +113,10 @@ def test_small_mesh_execute_train_step():
 def test_pipeline_forward_matches_sequential():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.parallel.pipeline import pipeline_forward, bubble_fraction
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         k, m, d = 4, 6, 16
         keys = jax.random.split(jax.random.key(0), k)
         stage_w = jax.vmap(lambda kk: jax.random.normal(kk, (d, d)) * 0.3)(keys)
@@ -142,9 +144,10 @@ def test_elastic_reshard_roundtrip(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint.manager import CheckpointManager
         from repro.checkpoint.reshard import reshard_tree
+        from repro.launch.mesh import make_mesh
 
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((4, 2), ("data", "model"))
         tree = {{"w": jax.device_put(
             jnp.arange(64.0).reshape(8, 8),
             NamedSharding(mesh_a, P("data", "model")))}}

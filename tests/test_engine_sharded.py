@@ -39,6 +39,7 @@ def run_driver(spec: dict, extra_env: dict | None = None,
                timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(REPO, "src"), os.path.join(REPO, "tests")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))

@@ -184,20 +184,10 @@ def test_scanned_generate_matches_python_loop():
 # satellites: backend-aware interpret + ACAMTable.padded
 # ---------------------------------------------------------------------------
 
-def test_resolve_interpret_backend_default(monkeypatch):
-    monkeypatch.delenv("NLDPE_FORCE_INTERPRET", raising=False)
+def test_resolve_interpret_backend_default():
     explicit_true, explicit_false = resolve_interpret(True), resolve_interpret(False)
     assert explicit_true is True and explicit_false is False
     assert resolve_interpret(None) == (jax.default_backend() == "cpu")
-
-
-def test_resolve_interpret_env_force(monkeypatch):
-    """NLDPE_FORCE_INTERPRET overrides everything (the CI numerics leg)."""
-    monkeypatch.setenv("NLDPE_FORCE_INTERPRET", "1")
-    assert resolve_interpret(None) is True
-    assert resolve_interpret(False) is True
-    monkeypatch.setenv("NLDPE_FORCE_INTERPRET", "0")
-    assert resolve_interpret(False) is False
 
 
 def test_acam_table_padded_up_and_down():

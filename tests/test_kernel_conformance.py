@@ -302,6 +302,7 @@ def test_paged_attention_sharded_conformance():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     script = textwrap.dedent(f"""
         import numpy as np

@@ -228,6 +228,21 @@ def test_tick_profiler_validates():
     assert p.logdir and not p.active and not p.done
 
 
+def test_tick_profiler_start_failure_raises(monkeypatch):
+    """A profiler that cannot start fails the run instead of silently
+    leaving it without the trace it asked for."""
+    import jax
+
+    def broken(logdir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", broken)
+    p = TickProfiler(None, 2)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        p.tick()
+    assert not p.active
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
