@@ -36,10 +36,14 @@ rules keep the host bookkeeping equally exact:
   they enter the drain queue pre-materialized and the pipeline depth
   degrades gracefully to admission-vs-decode overlap.
 
-Telemetry: the scheduler thread emits every event/record the tick loop
-would; the drain thread only adds a "drain" phase wall (``PhaseTimers`` is
-lock-guarded for exactly this cross-thread writer).  A "dispatch" phase
-meters the enqueue side of the pipeline.
+Telemetry: the caller's thread emits a "submit" event, the scheduler
+thread every event, record and span the tick loop would plus an
+"admission" span around each admission pass with its "flush" (the wait
+for in-flight ticks), and the drain thread only closes "drain" spans
+(the trace is lock-guarded for exactly this cross-thread writer).  A
+decode tick's "decode_block" span opens at its dispatch on the scheduler
+and closes at its harvest there; its "drain" child is parented across
+threads by id.
 """
 from __future__ import annotations
 
@@ -50,6 +54,7 @@ import time
 
 import numpy as np
 
+from ..obs import span
 from .engine import Completion, Request
 
 _STOP = object()
@@ -115,6 +120,9 @@ class AsyncServeEngine:
             self._pending.add(req.rid)
             self._submitted += 1
         self._start()
+        tel = self.engine.telemetry
+        if tel is not None:
+            tel.event("submit", self.engine.tick, rid=req.rid)
         self._submit_q.put(req)
 
     def results(self, *, timeout: float | None = None):
@@ -240,14 +248,13 @@ class AsyncServeEngine:
             item = self._drain_q.get()
             if item is _STOP:
                 return
-            emits, active, fin = item
+            emits, active, tick = item
             try:
-                t0 = time.perf_counter()
-                e = np.asarray(emits)
-                a = np.asarray(active)
-                if tel is not None:
-                    tel.phases.record("drain", time.perf_counter() - t0)
-                self._harvest_q.put((e, a, fin))
+                with span(tel, "drain", self.engine.tick,
+                          parent=tick and tick.id):
+                    e = np.asarray(emits)
+                    a = np.asarray(active)
+                self._harvest_q.put((e, a, tick))
             except BaseException as exc:          # forward, never die silent
                 self._harvest_q.put(exc)
 
@@ -257,7 +264,13 @@ class AsyncServeEngine:
         """Apply drained ticks to the engine, blocking until at most
         ``down_to`` dispatched ticks remain un-harvested.  ``down_to=0``
         is the pipeline flush that must precede every admission, resume,
-        or preemption decision."""
+        or preemption decision.
+
+        Completions are handed out at the end.  Once a harvest leaves no
+        slot active, the ticks still in flight emit nothing and are
+        harvested first, so a caller holding its last completion finds
+        the pipeline empty and may drive the engine itself."""
+        done = []
         while self._inflight:
             if self._inflight > down_to:
                 item = self._harvest_q.get()
@@ -265,15 +278,15 @@ class AsyncServeEngine:
                 try:
                     item = self._harvest_q.get_nowait()
                 except queue.Empty:
-                    return
+                    break
             self._inflight -= 1
             if isinstance(item, BaseException):
                 raise item
-            emits, active, fin = item
-            if fin is not None:
-                fin()
-            for comp in self.engine._harvest(emits, active):
-                self._finish(comp)
+            done += self.engine._collect(*item)
+            if done and not self.engine.any_active:
+                down_to = 0
+        for comp in done:
+            self._finish(comp)
 
     def _flush(self) -> None:
         if self._inflight:
@@ -324,26 +337,24 @@ class AsyncServeEngine:
             # harvested engine (see module docstring); only pay the flush
             # when one of them can actually happen
             if eng._preempted or (waiting and eng._can_admit(waiting)):
-                self._flush()
-                n_pre = len(eng._preempted)
-                eng._resume_preempted(waiting)
-                progressed |= len(eng._preempted) != n_pre
-                if waiting and eng._can_admit(waiting):
-                    wave = eng._select_wave(waiting)
-                    if wave:
-                        for comp in eng._admit_wave(wave):
-                            self._finish(comp)
-                        progressed = True
+                with span(tel, "admission", eng.tick):
+                    with span(tel, "flush", eng.tick):
+                        self._flush()
+                    n_pre = len(eng._preempted)
+                    eng._resume_preempted(waiting)
+                    progressed |= len(eng._preempted) != n_pre
+                    if waiting and eng._can_admit(waiting):
+                        wave = eng._select_wave(waiting)
+                        if wave:
+                            for comp in eng._admit_wave(wave):
+                                self._finish(comp)
+                            progressed = True
 
             if eng.any_active:
                 if self._inflight >= self.drain_depth:
                     self._apply_harvests(self.drain_depth - 1)
                     continue
-                t0 = time.perf_counter()
                 out = eng._dispatch_tick()
-                if tel is not None:
-                    tel.phases.record("dispatch",
-                                      time.perf_counter() - t0)
                 self._inflight += 1
                 self._max_inflight = max(self._max_inflight,
                                          self._inflight)

@@ -40,9 +40,10 @@ global.  Under the default rules, sharded outputs are bit-identical to
 
 **Async serving** (DESIGN.md §14): both engines expose their decode tick
 as ``_dispatch_tick()`` — device dispatch only, returning the emitted-token
-device buffer plus a freshly allocated active-mask snapshot — so
-``launch/async_engine.AsyncServeEngine`` can run dispatch and host-side
-harvest on separate threads (device never blocks on detokenize-side work).
+device buffer plus a freshly allocated active-mask snapshot and the tick's
+open telemetry span — and ``_collect()``, the host-side harvest, so
+``launch/async_engine.AsyncServeEngine`` can run the two on separate
+threads (device never blocks on detokenize-side work).
 With ``prefill_buckets`` set, admission-wave chunk prefill additionally
 runs as ONE per-bucket executable AOT-compiled at construction
 (``jax.jit(...).lower(...).compile()``), waves padded to the bucket edge
@@ -70,6 +71,7 @@ styles (DESIGN.md §5).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from collections import deque
@@ -84,7 +86,7 @@ from ..core import drift as drift_lib
 from ..core.engine import NLDPEConfig, OFF
 from ..models import lm
 from ..models.lm import ATTN_TYPES
-from ..obs import MetricsRegistry, Telemetry
+from ..obs import MetricsRegistry, Telemetry, span
 from ..parallel import sharding
 from ..parallel.context import sharding_ctx
 from .fidelity import DriftInjection, FidelityMonitor, FidelityPolicy
@@ -297,11 +299,14 @@ class ServeEngine:
         ``shard(...)`` constraints inside the model resolve against
         (mesh, rules) — including the all-gather constraints at contraction
         boundaries that keep exact-rule sharding bit-identical, and the
-        shard_map dispatch of the paged-attention kernel."""
+        shard_map dispatch of the paged-attention kernel.  The wrapper
+        keeps ``f``'s name, so a program is ``jit_decode`` or ``jit_chunk``
+        in a device trace with a mesh or without one."""
         if self.mesh is None:
             return f
         mesh, rules = self.mesh, self.rules
 
+        @functools.wraps(f)
         def traced(*args):
             with sharding_ctx(mesh, rules):
                 return f(*args)
@@ -605,58 +610,63 @@ class ServeEngine:
                 f"request {req.rid}: prompt {p} + {req.max_new_tokens} new "
                 f"tokens needs {need} positions > max_len={self.max_len}")
 
-    def _admit_wave(self, reqs: list[Request]) -> list[Completion]:
-        """Admit up to ``free_slots`` requests in one batched prefill."""
+    def _check_wave(self, reqs: list[Request]) -> None:
         assert len(reqs) <= self.free_slots
         rids = [r.rid for r in reqs]
         if len(set(rids)) != len(rids):
             raise ValueError(f"duplicate rids in one admission wave: {rids}")
         for r in reqs:
             self._validate(r)
+
+    def _admit_wave(self, reqs: list[Request]) -> list[Completion]:
+        """Admit up to ``free_slots`` requests in one batched prefill."""
+        self._check_wave(reqs)
         tel = self.telemetry
-        t_wave = tel.phases.now() if tel is not None else 0.0
         s, c = self.max_slots, self.prefill_chunk
-        slots = [self._free.popleft() for _ in reqs]
-        admit = np.zeros((s,), bool)
-        plen = np.ones((s,), np.int32)          # 1 avoids 0-len edge cases
-        for r, sl in zip(reqs, slots):
-            admit[sl] = True
-            plen[sl] = len(r.tokens)
-        n_chunks = -(-int(plen[admit].max()) // c)
-        tokens = np.zeros((s, n_chunks * c), np.int32)
-        for r, sl in zip(reqs, slots):
-            tokens[sl, :len(r.tokens)] = r.tokens
+        with span(tel, "admission_wave", self.tick,
+                  rids=[r.rid for r in reqs], n_reqs=len(reqs)) as wave:
+            with span(tel, "plan", self.tick):
+                slots = [self._free.popleft() for _ in reqs]
+                admit = np.zeros((s,), bool)
+                plen = np.ones((s,), np.int32)   # 1 avoids 0-len edge cases
+                for r, sl in zip(reqs, slots):
+                    admit[sl] = True
+                    plen[sl] = len(r.tokens)
+                n_chunks = -(-int(plen[admit].max()) // c)
+                tokens = np.zeros((s, n_chunks * c), np.int32)
+                # per-slot (chunk, column) of the last real prompt token
+                ci_np = np.zeros((s,), np.int32)
+                col_np = np.zeros((s,), np.int32)
+                keys_np = np.zeros((s, 2), np.uint32)
+                temp_np = np.zeros((s,), np.float32)
+                topk_np = np.zeros((s,), np.int32)
+                for r, sl in zip(reqs, slots):
+                    tokens[sl, :len(r.tokens)] = r.tokens
+                    ci_np[sl] = (len(r.tokens) - 1) // c
+                    col_np[sl] = (len(r.tokens) - 1) % c
+                    keys_np[sl] = np.asarray(
+                        request_key(r.seed if r.seed is not None else r.rid))
+                    temp_np[sl] = r.temperature
+                    topk_np[sl] = r.top_k
+            with span(tel, "prefill", self.tick):
+                last, wave["n_chunks"] = self._prefill_chunks(
+                    admit, plen, np.zeros((s,), np.int32), tokens, ci_np,
+                    col_np)
+            return self._seat(reqs, slots, last, keys_np, plen, temp_np,
+                              topk_np, [(0, 0)] * len(reqs))
 
-        # per-slot (chunk, column) of the last real prompt token
-        ci_np = np.zeros((s,), np.int32)
-        col_np = np.zeros((s,), np.int32)
-        keys_np = np.zeros((s, 2), np.uint32)
-        pos_np = np.ones((s,), np.int32)
-        temp_np = np.zeros((s,), np.float32)
-        topk_np = np.zeros((s,), np.int32)
-        for r, sl in zip(reqs, slots):
-            ci_np[sl] = (len(r.tokens) - 1) // c
-            col_np[sl] = (len(r.tokens) - 1) % c
-            keys_np[sl] = np.asarray(
-                request_key(r.seed if r.seed is not None else r.rid))
-            pos_np[sl] = len(r.tokens)
-            temp_np[sl] = r.temperature
-            topk_np[sl] = r.top_k
-
-        last, n_disp = self._prefill_chunks(
-            admit, plen, np.zeros((s,), np.int32), tokens, ci_np, col_np)
-
-        all_firsts = np.asarray(self._sample_fn(
-            last, jnp.asarray(keys_np), jnp.asarray(pos_np),
-            jnp.asarray(temp_np), jnp.asarray(topk_np)))
-        firsts = [all_firsts[sl] for sl in slots]
-        if tel is not None:
-            # all_firsts materialized above — the whole wave's device work
-            # is already synchronized, so the bracket closes here for free
-            wall = tel.phases.add("admission", t_wave)
-            tel.event("admission_wave", self.tick, n_reqs=len(reqs),
-                      n_chunks=n_disp, wall_s=wall)
-
+    def _seat(self, reqs, slots, last, keys_np, plen_np, temp_np, topk_np,
+              notes) -> list[Completion]:
+        """The end of every admission wave: sample and sync the first
+        tokens, record them, retire instant finishes (releasing their
+        slots), and merge decode state for the rest in one jit.  ``notes``
+        is each request's (prefix reuse, pages held) for its record."""
+        tel = self.telemetry
+        s = self.max_slots
+        with span(tel, "first_token_sync", self.tick):
+            all_firsts = np.asarray(self._sample_fn(
+                last, jnp.asarray(keys_np), jnp.asarray(plen_np),
+                jnp.asarray(temp_np), jnp.asarray(topk_np)))
         done: list[Completion] = []
         sel = np.zeros((s,), bool)
         n_tok = np.zeros((s,), np.int32)
@@ -665,12 +675,12 @@ class ServeEngine:
         n_temp = np.zeros((s,), np.float32)
         n_topk = np.zeros((s,), np.int32)
         n_keys = np.zeros((s, 2), np.uint32)
-        for r, sl, first in zip(reqs, slots, firsts):
-            first = int(first)
+        for r, sl, (reuse, pages) in zip(reqs, slots, notes):
+            first = int(all_firsts[sl])
             self._out[r.rid] = [first]
             self._admitted_tick[r.rid] = self.tick
             if tel is not None:
-                self._tel_note_admit(r, sl)
+                self._tel_note_admit(r, sl, reuse=reuse, pages_held=pages)
             if r.max_new_tokens == 1 or (self.eos_id >= 0
                                          and first == self.eos_id):
                 self._release_slot(sl)
@@ -688,13 +698,14 @@ class ServeEngine:
             n_keys[sl] = keys_np[sl]
 
         if sel.any():
-            (self._tok, self._pos, self._active, self._gen_left, self._temp,
-             self._topk, self._keys) = self._state_fn(
-                self._tok, self._pos, self._active, self._gen_left,
-                self._temp, self._topk, self._keys, jnp.asarray(sel),
-                jnp.asarray(n_tok), jnp.asarray(n_pos), jnp.asarray(n_gen),
-                jnp.asarray(n_temp), jnp.asarray(n_topk),
-                jnp.asarray(n_keys))
+            with span(tel, "merge", self.tick):
+                (self._tok, self._pos, self._active, self._gen_left,
+                 self._temp, self._topk, self._keys) = self._state_fn(
+                    self._tok, self._pos, self._active, self._gen_left,
+                    self._temp, self._topk, self._keys, jnp.asarray(sel),
+                    jnp.asarray(n_tok), jnp.asarray(n_pos),
+                    jnp.asarray(n_gen), jnp.asarray(n_temp),
+                    jnp.asarray(n_topk), jnp.asarray(n_keys))
         return done
 
     @staticmethod
@@ -802,45 +813,61 @@ class ServeEngine:
         """Device work of one decode tick, no host-side harvest.
 
         Dispatches the scanned decode jit and returns ``(emits, active,
-        fin)``: the (T, S) emitted-token buffer, the post-tick active mask
+        tick)``: the (T, S) emitted-token buffer, the post-tick active mask
         in a freshly allocated buffer (the next tick donates the live
-        one), and ``fin`` — a host callback closing the tick's telemetry
-        bracket once a consumer has materialized ``emits`` (None when that
-        already happened, or with telemetry off).  :meth:`step`
-        materializes inline; the async engine hands the triple to its
-        drain thread so device dispatch never blocks on host work."""
+        one), and ``tick`` — the tick's open ``decode_block`` span, which
+        :meth:`_collect` closes once the tokens are harvested (None with
+        telemetry off).  :meth:`step` materializes inline; the async engine
+        hands the triple to its drain thread so device dispatch never
+        blocks on host work."""
         tel = self.telemetry
-        t0 = 0.0
+        tick = None
         if tel is not None:
             tel.tick_boundary(self.tick)
-            t0 = tel.phases.now()
-        (self.cache, self._tok, self._pos, self._active, self._gen_left,
-         emits) = self._decode_fn(self.params, self.cache, self._tok,
-                                  self._pos, self._active, self._gen_left,
-                                  self._temp, self._topk, self._keys)
-        self.tick += self.decode_block
-        active = self._snap_fn(self._active)
-        if tel is None:
-            return emits, active, None
-        tick_after = self.tick
-        n_active = sum(o is not None for o in self._slot_owner)
-
-        def fin():
-            wall = tel.phases.add("decode", t0)
-            tel.event("decode_block", tick_after, n_active=n_active,
-                      block=self.decode_block, wall_s=wall)
-
-        return emits, active, fin
+            tick = tel.trace.open(
+                "decode_block", self.tick, block=self.decode_block,
+                n_active=sum(o is not None for o in self._slot_owner))
+        with span(tel, "dispatch", self.tick, parent=tick and tick.id):
+            (self.cache, self._tok, self._pos, self._active, self._gen_left,
+             emits) = self._decode_fn(self.params, self.cache, self._tok,
+                                      self._pos, self._active,
+                                      self._gen_left, self._temp, self._topk,
+                                      self._keys)
+            self.tick += self.decode_block
+            active = self._snap_fn(self._active)
+        if tick is not None:
+            tick.tick = self.tick
+        return emits, active, tick
 
     def step(self) -> list[Completion]:
         """One decode tick: ``decode_block`` scanned steps over all slots.
         Returns the requests that finished during the tick."""
-        emits, active, fin = self._dispatch_tick()
-        emits = np.asarray(emits)       # the tick's one existing host sync
-        active = np.asarray(active)
-        if fin is not None:
-            fin()
-        return self._harvest(emits, active)
+        emits, active, tick = self._dispatch_tick()
+        with span(self.telemetry, "drain", self.tick,
+                  parent=tick and tick.id):
+            emits = np.asarray(emits)   # the tick's one existing host sync
+            active = np.asarray(active)
+        return self._collect(emits, active, tick)
+
+    def _collect(self, emits: np.ndarray, active: np.ndarray,
+                 tick) -> list[Completion]:
+        """:meth:`_harvest` one materialized tick inside a ``harvest`` span
+        that records the tokens each request received, then close the
+        tick's ``decode_block`` span (``tick``, None with telemetry off or
+        for a speculative tick)."""
+        tel = self.telemetry
+        if tel is None:
+            return self._harvest(emits, active)
+        owners = [(s, r.rid) for s, r in enumerate(self._slot_owner)
+                  if r is not None]
+        got = (emits >= 0).sum(axis=0)
+        with tel.trace.span("harvest", self.tick, parent=tick and tick.id,
+                            rids=[rid for _, rid in owners],
+                            n_emitted=[int(got[s]) for s, _ in owners]):
+            done = self._harvest(emits, active)
+        if tick is not None:
+            tel.trace.close(tick)
+        return done
 
     def _harvest(self, emits: np.ndarray,
                  active: np.ndarray) -> list[Completion]:
@@ -1322,40 +1349,33 @@ class PagedServeEngine(ServeEngine):
         # perf_counter, not time.time(): the wall clock can step backwards
         # under NTP, which produced negative draft phases in long serves
         t0 = time.perf_counter()
-        self.cache, drafts, q_probs = draft_fn(
-            dparams, self.cache, self._tok, self._pos, self._active,
-            self._temp, self._topk, self._keys)
-        jax.block_until_ready(drafts)       # meter the analog phase alone
-        dt_draft = time.perf_counter() - t0
-        self.spec_draft_seconds += dt_draft
-        if tel is not None:
-            tel.phases.record("draft", dt_draft)
-            tel.event("spec_draft", self.tick, k=k,
-                      n_active=int(pre_active.sum()), wall_s=dt_draft)
-        t1 = time.perf_counter()
-        (self.cache, self._tok, self._pos, self._active, self._gen_left,
-         emits, accepted) = verify_fn(
-            self.params, self.cache, self._tok, self._pos, self._active,
-            self._gen_left,
-            self._temp, self._topk, self._keys, drafts, q_probs)
-        self.tick += 1
-        self._spec_steps += 1
-        drafted_now = np.where(pre_active, k, 0).astype(np.int64)
-        accepted_now = np.where(pre_active, np.asarray(accepted),
-                                0).astype(np.int64)
-        self._drafted += drafted_now
-        self._accepted += accepted_now
-        self._win_drafted += drafted_now
-        self._win_accepted += accepted_now
-        self._win_ticks += 1
-        d, a = int(drafted_now.sum()), int(accepted_now.sum())
-        if tel is not None:
-            # np.asarray(accepted) above already synchronized the verify
-            # outputs — the bracket closes on that existing sync
-            dt_verify = tel.phases.record("verify",
-                                          time.perf_counter() - t1)
-            tel.event("spec_verify", self.tick, k=k, drafted=d, accepted=a,
-                      wall_s=dt_verify)
+        with span(tel, "spec_draft", self.tick, k=k,
+                  n_active=int(pre_active.sum())):
+            self.cache, drafts, q_probs = draft_fn(
+                dparams, self.cache, self._tok, self._pos, self._active,
+                self._temp, self._topk, self._keys)
+            jax.block_until_ready(drafts)   # meter the analog phase alone
+        self.spec_draft_seconds += time.perf_counter() - t0
+        # the span closes on np.asarray(accepted), the verify outputs'
+        # existing sync
+        with span(tel, "spec_verify", self.tick, k=k) as verify:
+            (self.cache, self._tok, self._pos, self._active, self._gen_left,
+             emits, accepted) = verify_fn(
+                self.params, self.cache, self._tok, self._pos, self._active,
+                self._gen_left,
+                self._temp, self._topk, self._keys, drafts, q_probs)
+            self.tick += 1
+            self._spec_steps += 1
+            drafted_now = np.where(pre_active, k, 0).astype(np.int64)
+            accepted_now = np.where(pre_active, np.asarray(accepted),
+                                    0).astype(np.int64)
+            self._drafted += drafted_now
+            self._accepted += accepted_now
+            self._win_drafted += drafted_now
+            self._win_accepted += accepted_now
+            self._win_ticks += 1
+            d, a = int(drafted_now.sum()), int(accepted_now.sum())
+            verify["drafted"], verify["accepted"] = d, a
         if d:
             acc = a / d
             self.ewma_acceptance = (
@@ -1772,15 +1792,45 @@ class PagedServeEngine(ServeEngine):
     # ------------------------------------------------------------------
 
     def _admit_wave(self, reqs: list[Request]) -> list[Completion]:
-        assert len(reqs) <= self.free_slots
-        rids = [r.rid for r in reqs]
-        if len(set(rids)) != len(rids):
-            raise ValueError(f"duplicate rids in one admission wave: {rids}")
-        for r in reqs:
-            self._validate(r)
+        self._check_wave(reqs)
         tel = self.telemetry
-        t_wave = tel.phases.now() if tel is not None else 0.0
-        s, c, ps = self.max_slots, self.prefill_chunk, self.page_size
+        s, c = self.max_slots, self.prefill_chunk
+        with span(tel, "admission_wave", self.tick,
+                  rids=[r.rid for r in reqs], n_reqs=len(reqs)) as wave:
+            with span(tel, "plan", self.tick):
+                slots, plans, admit, reuse_np, plen_np = self._plan_wave(
+                    reqs)
+            # Phase 4 — chunked SUFFIX prefill at per-slot base positions.
+            with span(tel, "prefill", self.tick):
+                suffix = plen_np - reuse_np        # >= 1: last token always
+                n_chunks = -(-int(suffix[admit].max()) // c)
+                tokens = np.zeros((s, n_chunks * c), np.int32)
+                ci_np = np.zeros((s,), np.int32)
+                col_np = np.zeros((s,), np.int32)
+                keys_np = np.zeros((s, 2), np.uint32)
+                temp_np = np.zeros((s,), np.float32)
+                topk_np = np.zeros((s,), np.int32)
+                for r, sl, plan in zip(reqs, slots, plans):
+                    tokens[sl, :suffix[sl]] = r.tokens[plan["reuse"]:]
+                    ci_np[sl] = (suffix[sl] - 1) // c
+                    col_np[sl] = (suffix[sl] - 1) % c
+                    keys_np[sl] = np.asarray(
+                        request_key(r.seed if r.seed is not None else r.rid))
+                    temp_np[sl] = r.temperature
+                    topk_np[sl] = r.top_k
+                last, wave["n_chunks"] = self._prefill_chunks(
+                    admit, plen_np, reuse_np, tokens, ci_np, col_np)
+            # Phase 5 — the slotted engine's post-prefill bookkeeping
+            return self._seat(reqs, slots, last, keys_np, plen_np, temp_np,
+                              topk_np, [(p["reuse"], p["nb_need"])
+                                        for p in plans])
+
+    def _plan_wave(self, reqs: list[Request]):
+        """Phases 1-3 of an admission wave, all host work and page copies:
+        take slots, plan and commit pages, publish, reset block tables.
+        Returns (slots, plans, admit, reuse, plen)."""
+        tel = self.telemetry
+        s, ps = self.max_slots, self.page_size
 
         # Phase 1 — plan + commit pool state for every request BEFORE any
         # publish: requests in one wave never share each other's pages
@@ -1893,77 +1943,4 @@ class PagedServeEngine(ServeEngine):
         self.cache = self._setup_fn(self.cache, jnp.asarray(admit),
                                     jnp.asarray(reuse_np),
                                     jnp.asarray(new_bt))
-
-        # Phase 4 — chunked SUFFIX prefill at per-slot base positions.
-        suffix = plen_np - reuse_np                # >= 1: last token always
-        n_chunks = -(-int(suffix[admit].max()) // c)
-        tokens = np.zeros((s, n_chunks * c), np.int32)
-        ci_np = np.zeros((s,), np.int32)
-        col_np = np.zeros((s,), np.int32)
-        keys_np = np.zeros((s, 2), np.uint32)
-        temp_np = np.zeros((s,), np.float32)
-        topk_np = np.zeros((s,), np.int32)
-        for r, sl, plan in zip(reqs, slots, plans):
-            tokens[sl, :suffix[sl]] = r.tokens[plan["reuse"]:]
-            ci_np[sl] = (suffix[sl] - 1) // c
-            col_np[sl] = (suffix[sl] - 1) % c
-            keys_np[sl] = np.asarray(
-                request_key(r.seed if r.seed is not None else r.rid))
-            temp_np[sl] = r.temperature
-            topk_np[sl] = r.top_k
-
-        last, n_disp = self._prefill_chunks(
-            admit, plen_np, reuse_np, tokens, ci_np, col_np)
-
-        all_firsts = np.asarray(self._sample_fn(
-            last, jnp.asarray(keys_np), jnp.asarray(plen_np),
-            jnp.asarray(temp_np), jnp.asarray(topk_np)))
-        firsts = [all_firsts[sl] for sl in slots]
-        if tel is not None:
-            wall = tel.phases.add("admission", t_wave)
-            tel.event("admission_wave", self.tick, n_reqs=len(reqs),
-                      n_chunks=n_disp, wall_s=wall)
-
-        # Phase 5 — identical post-prefill bookkeeping to the slotted
-        # engine: record first tokens, retire instant finishes (releasing
-        # their pages), merge decode state for the rest in one jit.
-        done: list[Completion] = []
-        sel = np.zeros((s,), bool)
-        n_tok = np.zeros((s,), np.int32)
-        n_pos = np.zeros((s,), np.int32)
-        n_gen = np.zeros((s,), np.int32)
-        n_temp = np.zeros((s,), np.float32)
-        n_topk = np.zeros((s,), np.int32)
-        n_keys = np.zeros((s, 2), np.uint32)
-        for r, sl, first, plan in zip(reqs, slots, firsts, plans):
-            first = int(first)
-            self._out[r.rid] = [first]
-            self._admitted_tick[r.rid] = self.tick
-            if tel is not None:
-                self._tel_note_admit(r, sl, reuse=plan["reuse"],
-                                     pages_held=plan["nb_need"])
-            if r.max_new_tokens == 1 or (self.eos_id >= 0
-                                         and first == self.eos_id):
-                self._release_slot(sl)
-                self._free.appendleft(sl)
-                done.append(self._complete(
-                    r, "eos" if first == self.eos_id else "length"))
-                continue
-            self._slot_owner[sl] = r
-            sel[sl] = True
-            n_tok[sl] = first
-            n_pos[sl] = len(r.tokens)
-            n_gen[sl] = r.max_new_tokens - 1
-            n_temp[sl] = r.temperature
-            n_topk[sl] = r.top_k
-            n_keys[sl] = keys_np[sl]
-
-        if sel.any():
-            (self._tok, self._pos, self._active, self._gen_left, self._temp,
-             self._topk, self._keys) = self._state_fn(
-                self._tok, self._pos, self._active, self._gen_left,
-                self._temp, self._topk, self._keys, jnp.asarray(sel),
-                jnp.asarray(n_tok), jnp.asarray(n_pos), jnp.asarray(n_gen),
-                jnp.asarray(n_temp), jnp.asarray(n_topk),
-                jnp.asarray(n_keys))
-        return done
+        return slots, plans, admit, reuse_np, plen_np

@@ -198,7 +198,7 @@ def _report_obs(eng, args) -> None:
               f"{ms(tt['p99'])}, TPOT p50/p99 {ms(tp['p50'])}/"
               f"{ms(tp['p99'])}, queue-wait p99 {ms(qw['p99'])}")
         for phase, d in s["phases"].items():
-            print(f"    phase {phase:>9}: {d['seconds'] * 1e3:8.1f} ms "
+            print(f"    phase {phase:>16}: {d['seconds'] * 1e3:8.1f} ms "
                   f"over {d['calls']} calls")
         if args.trace_out:
             n = tel.flush_jsonl(args.trace_out)

@@ -9,12 +9,12 @@ enabled is token-bit-identical to serving with it disabled
 evidence for visibility.
 """
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .telemetry import (BoundedLog, EVENT_SCHEMA, EventTrace, PhaseTimers,
-                        Percentiles, RequestRecord, SCHEMA_VERSION,
-                        Telemetry, TickProfiler)
+from .telemetry import (BoundedLog, EVENT_SCHEMA, EventTrace, Percentiles,
+                        RequestRecord, SCHEMA_VERSION, Telemetry, TickProfiler,
+                        span)
 
 __all__ = [
     "BoundedLog", "Counter", "EVENT_SCHEMA", "EventTrace", "Gauge",
-    "Histogram", "MetricsRegistry", "Percentiles", "PhaseTimers",
-    "RequestRecord", "SCHEMA_VERSION", "Telemetry", "TickProfiler",
+    "Histogram", "MetricsRegistry", "Percentiles", "RequestRecord",
+    "SCHEMA_VERSION", "Telemetry", "TickProfiler", "span",
 ]

@@ -3,12 +3,28 @@
 Three cooperating pieces, all host-side and allocation-bounded:
 
 * :class:`EventTrace` — a ring buffer of typed events with a committed
-  schema (:data:`EVENT_SCHEMA`).  Every event the engines emit — admission
-  waves, decode blocks, the draft/verify split, COW forks, evictions,
-  fidelity-ladder transitions, request lifecycle edges — is one dict
-  validated against the schema at emit time and flushable as JSONL.  The
-  buffer is a ``deque(maxlen=capacity)``: a week-long serve cannot grow it,
-  old events fall off the far end and are *counted*, never silently lost.
+  schema (:data:`EVENT_SCHEMA`).  Every event the engines emit — request
+  lifecycle edges, COW forks, evictions, fidelity-ladder transitions,
+  compiles — is one dict validated against the schema at emit time and
+  flushable as JSONL.  The buffer is a ``deque(maxlen=capacity)``: a
+  week-long serve cannot grow it, old events fall off the far end and are
+  *counted*, never silently lost.
+
+* **Spans** — the engine's phases (scheduler pass, admission wave and its
+  parts, decode tick with its dispatch, drain and harvest, the draft/verify
+  split) are events of the same trace that also carry their start ``t0``,
+  an id ``span`` and the ``parent`` span's id.  A same-thread span is a
+  context manager (:meth:`EventTrace.span`) whose parent is the innermost
+  open span on its thread, and it is entered as a
+  ``jax.profiler.TraceAnnotation("nldpe.<kind>")`` at the same two
+  boundaries, so a profile shows the program's spans on the profiler's own
+  clock beside the device ops.  The decode tick, dispatched on one thread
+  and materialized on another, is an :meth:`EventTrace.open` /
+  :meth:`EventTrace.close` pair.  Closed spans fold into per-kind lifetime
+  totals (``Telemetry.summary()["phases"]``).  **Sync discipline**: a span
+  closes only where the engine already synchronizes or does host work;
+  telemetry never adds a device sync, so the async dispatch pipeline is
+  unchanged and the enabled-vs-disabled token streams stay bit-identical.
 
 * :class:`RequestRecord` + :class:`Percentiles` — the per-request
   lifecycle (enqueue → admit → first token → finish) measured on
@@ -17,33 +33,28 @@ Three cooperating pieces, all host-side and allocation-bounded:
   queue wait, pages held, and per-request speculative acceptance, folded
   into streaming p50/p90/p99 summaries.
 
-* :class:`PhaseTimers` — wall-clock accumulators per engine phase
-  (admission / decode / draft / verify).  **Sync discipline**: timers wrap
-  only regions the engine already synchronizes (``np.asarray`` of emitted
-  tokens, the draft-phase ``block_until_ready``); telemetry never adds a
-  device sync of its own, so the async dispatch pipeline is unchanged and
-  the enabled-vs-disabled token streams stay bit-identical.
-
 :class:`TickProfiler` is the opt-in deep lens: capture N engine ticks with
 ``jax.profiler`` (perfetto-viewable trace) and stop — serving continues.
 
-The accumulators (:class:`BoundedLog`, :class:`Percentiles`,
-:class:`PhaseTimers`) are internally locked: the async serve pipeline
-(DESIGN.md §14) has its drain thread fold "drain" phase walls while the
-scheduler thread owns every other write, and a reader may snapshot
-mid-serve.  The locks bound tiny host-side critical sections — never a
-device sync — so the zero-behavioral-footprint bar is untouched.
+The accumulators (:class:`BoundedLog`, :class:`Percentiles`, the span
+totals) are internally locked: the async serve pipeline (DESIGN.md §14)
+has its drain thread close "drain" spans while the scheduler thread owns
+every other write, and a reader may snapshot mid-serve.  The locks bound
+tiny host-side critical sections — never a device sync — so the
+zero-behavioral-footprint bar is untouched.
 
 Nothing here imports from ``launch`` (the engines import *us*), and jax is
-imported only inside the profiler, so the module stays a pure host-side
-dependency.
+imported lazily (the profiler, the annotations, the compile listener), so
+the module stays importable without it.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import threading
 import time
+import weakref
 from collections import deque
 
 import numpy as np
@@ -55,24 +66,55 @@ import numpy as np
 #: Version stamp written into every JSONL flush; bump on any field change.
 #: v2: hierarchical-cache events — "spill" reshaped from its reserved
 #: placeholder to per-page, plus "restore"/"preempt"/"resume".
-SCHEMA_VERSION = 2
+#: v3: spans — phase kinds carry :data:`SPAN_FIELDS` in place of their
+#: ``wall_s`` payload (duration is ``t - t0``); new kinds "submit",
+#: "compile" and the spans "admission", "flush", "plan", "prefill",
+#: "first_token_sync", "merge", "dispatch", "drain", "harvest".
+SCHEMA_VERSION = 3
+
+#: Fields every span carries besides its payload: its start (``t`` is its
+#: end, both on the trace clock), its id, and the id of the span that
+#: encloses it (None at the root).
+SPAN_FIELDS = ("t0", "span", "parent")
+
+
+def _span(*payload: str) -> tuple[str, ...]:
+    return SPAN_FIELDS + payload
+
 
 #: Committed schema: event kind -> exactly these payload fields (every
 #: event additionally carries the BASE_FIELDS).  ``emit`` validates the
 #: field *set* — a call site cannot drift from the schema unnoticed, and a
 #: consumer can rely on every field being present.
 EVENT_SCHEMA: dict[str, tuple[str, ...]] = {
-    # request lifecycle edges
+    # request lifecycle edges; "submit" is the async front end's caller
+    # thread, "enqueue" the scheduler's ingest of the request
+    "submit": ("rid",),
     "enqueue": ("rid",),
     "admit": ("rid", "slot", "prompt_len", "reuse", "queue_wait_ticks"),
     "first_token": ("rid",),
     "finish": ("rid", "reason", "n_tokens", "ttft_s", "tpot_s",
                "queue_wait_s", "pages_held", "drafted", "accepted"),
-    # engine phases
-    "admission_wave": ("n_reqs", "n_chunks", "wall_s"),
-    "decode_block": ("n_active", "block", "wall_s"),
-    "spec_draft": ("k", "n_active", "wall_s"),
-    "spec_verify": ("k", "drafted", "accepted", "wall_s"),
+    # scheduler spans: one admission pass (flush, resume, wave), the wait
+    # for in-flight ticks, and the wave with its host plan, chunk
+    # dispatches, first-token sync and decode-state merge
+    "admission": _span(),
+    "flush": _span(),
+    "admission_wave": _span("rids", "n_reqs", "n_chunks"),
+    "plan": _span(),
+    "prefill": _span(),
+    "first_token_sync": _span(),
+    "merge": _span(),
+    # decode tick (cross-thread: dispatch -> harvest) and its parts;
+    # harvest carries each request's tokens received (emission stamps)
+    "decode_block": _span("n_active", "block"),
+    "dispatch": _span(),
+    "drain": _span(),
+    "harvest": _span("rids", "n_emitted"),
+    "spec_draft": _span("k", "n_active"),
+    "spec_verify": _span("k", "drafted", "accepted"),
+    # a jax compile stage, parented to the innermost open span
+    "compile": ("stage", "fun", "seconds", "parent"),
     # paged-pool lifecycle
     "cow_fork": ("src", "dst"),          # src == -1: forked off a host
     #                                      payload (spilled boundary page)
@@ -87,10 +129,16 @@ EVENT_SCHEMA: dict[str, tuple[str, ...]] = {
     "fidelity": ("kind", "spec_k", "ewma", "vclock_s"),
 }
 
+_SCHEMA_KEYS = {ev: frozenset(f) for ev, f in EVENT_SCHEMA.items()}
+_ANNOTATIONS = {ev: f"nldpe.{ev}" for ev in EVENT_SCHEMA}
+
 #: Fields every event carries: kind, wall timestamp (perf_counter), the
 #: engine tick it was observed at, and a monotone sequence number (gaps
 #: after a flush reveal ring overwrites).
 BASE_FIELDS = ("ev", "t", "tick", "seq")
+
+#: Prefix of the ``jax.monitoring`` duration events of a compile.
+COMPILE_EVENTS = "/jax/core/compile/"
 
 
 class BoundedLog:
@@ -137,12 +185,87 @@ class BoundedLog:
             self.dropped = 0
 
 
+_TOP = object()          # span parent: the innermost open span on the thread
+_annotation = None       # jax.profiler.TraceAnnotation, imported on first use
+
+
+class Span:
+    """One span while it is open; ``span[field] = value`` fills a payload
+    field known only later (a wave's chunk count).  Use it through
+    :meth:`EventTrace.span` (same thread, a context manager) or
+    :meth:`EventTrace.open` / :meth:`EventTrace.close` (across threads)."""
+
+    __slots__ = ("_trace", "ev", "tick", "fields", "id", "parent", "t0",
+                 "_ann")
+
+    def __init__(self, trace: "EventTrace", ev: str, tick: int, parent,
+                 fields: dict):
+        self._trace = trace
+        self.ev = ev
+        self.tick = tick
+        self.fields = fields
+        self.id = next(trace._ids)
+        self.parent = parent
+        self.t0 = 0.0
+        self._ann = None
+
+    def __setitem__(self, field: str, value) -> None:
+        self.fields[field] = value
+
+    def __enter__(self) -> "Span":
+        global _annotation
+        stack = self._trace._stack()
+        if self.parent is _TOP:
+            self.parent = stack[-1].id if stack else None
+        stack.append(self)
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        self._ann = _annotation(_ANNOTATIONS[self.ev])
+        self._ann.__enter__()
+        self.t0 = self._trace._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t = None if exc_type is not None else self._trace._clock()
+        self._ann.__exit__(exc_type, exc, tb)
+        self._trace._stack().pop()
+        if t is not None:                # a failed phase records nothing
+            self._trace._close(self, t)
+        return False
+
+
+class _NullSpan:
+    """What :func:`span` hands out with telemetry off: a no-op."""
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+    def __setitem__(self, field: str, value) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+def span(tel: "Telemetry | None", ev: str, tick: int, **fields):
+    """``tel.trace.span(...)``, or a no-op when telemetry is off — so an
+    engine call site is one ``with`` and no guard."""
+    if tel is None:
+        return NULL_SPAN
+    return tel.trace.span(ev, tick, **fields)
+
+
 class EventTrace(BoundedLog):
-    """Bounded structured trace of typed serve events.
+    """Bounded structured trace of typed serve events and spans.
 
     ``emit`` validates the payload field set against :data:`EVENT_SCHEMA`
     (exact match — missing and extra fields both raise: the schema is a
-    contract, not a suggestion) and stamps the base fields.
+    contract, not a suggestion) and stamps the base fields.  Spans add
+    :data:`SPAN_FIELDS` themselves and are emitted when they close.
     """
 
     def __init__(self, capacity: int = 4096,
@@ -150,22 +273,81 @@ class EventTrace(BoundedLog):
         super().__init__(capacity)
         self._clock = clock
         self._seq = 0
+        self._ids = itertools.count()
+        self._local = threading.local()
+        self._totals: dict[str, list] = {}      # kind -> [seconds, calls]
 
     def emit(self, ev: str, tick: int, **fields) -> dict:
-        want = EVENT_SCHEMA.get(ev)
+        return self._emit(ev, tick, None, fields)
+
+    def _emit(self, ev: str, tick: int, t, fields: dict,
+              t0: float | None = None) -> dict:
+        """Validate and append one event; ``t`` None stamps it now, and a
+        span's ``t0`` folds ``t - t0`` into its kind's totals."""
+        want = _SCHEMA_KEYS.get(ev)
         if want is None:
             raise ValueError(f"unknown event kind {ev!r} "
                              f"(EVENT_SCHEMA has {sorted(EVENT_SCHEMA)})")
-        if set(fields) != set(want):
+        if fields.keys() != want:
             raise ValueError(
                 f"event {ev!r} fields {sorted(fields)} != schema "
                 f"{sorted(want)}")
         with self._lock:
-            rec = {"ev": ev, "t": self._clock(), "tick": int(tick),
-                   "seq": self._seq, **fields}
+            rec = {"ev": ev, "t": self._clock() if t is None else t,
+                   "tick": int(tick), "seq": self._seq, **fields}
             self._seq += 1
             self.append(rec)
+            if t0 is not None:
+                tot = self._totals.setdefault(ev, [0.0, 0])
+                tot[0] += rec["t"] - t0
+                tot[1] += 1
         return rec
+
+    # -- spans -------------------------------------------------------------
+
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def current(self):
+        """Id of the innermost span open on the calling thread, or None."""
+        stack = self._stack()
+        return stack[-1].id if stack else None
+
+    def span(self, ev: str, tick: int, *, parent=_TOP, **fields) -> Span:
+        """A same-thread span: ``with trace.span(kind, tick, ...) as sp``.
+        Its parent is the innermost span open on this thread unless
+        ``parent`` names one (a child of a cross-thread span)."""
+        return Span(self, ev, tick, parent, fields)
+
+    def open(self, ev: str, tick: int, *, parent=None, **fields) -> Span:
+        """Open a span that another thread may close (:meth:`close`); it
+        is not on any thread's stack and has no profiler annotation."""
+        sp = Span(self, ev, tick, parent, fields)
+        sp.t0 = self._clock()
+        return sp
+
+    def close(self, sp: Span) -> dict:
+        return self._close(sp, self._clock())
+
+    def _close(self, sp: Span, t: float) -> dict:
+        return self._emit(sp.ev, sp.tick, t,
+                          {"t0": sp.t0, "span": sp.id, "parent": sp.parent,
+                           **sp.fields}, sp.t0)
+
+    def totals(self) -> dict:
+        """Lifetime ``{kind: {"seconds", "calls"}}`` of the closed spans."""
+        with self._lock:
+            return {k: {"seconds": s, "calls": n}
+                    for k, (s, n) in self._totals.items()}
+
+    def clear(self) -> None:
+        with self._lock:
+            super().clear()
+            self._seq = 0
+            self._totals.clear()
 
     def flush_jsonl(self, path) -> int:
         """Write the buffered events as JSON Lines: one meta record (schema
@@ -185,7 +367,7 @@ class EventTrace(BoundedLog):
 
 
 # ---------------------------------------------------------------------------
-# streaming percentiles + phase timers
+# streaming percentiles
 # ---------------------------------------------------------------------------
 
 class Percentiles:
@@ -239,49 +421,6 @@ class Percentiles:
             self._vals.clear()
             self.count = 0
             self.total = 0.0
-
-
-class PhaseTimers:
-    """Per-phase wall accumulators on ``time.perf_counter``.
-
-    Used bracket-style (``t0 = timers.now(); ...; timers.add(phase, t0)``)
-    so the engine controls exactly where the brackets sit — always at
-    boundaries it already synchronizes.  ``add`` returns the elapsed wall
-    seconds so the same measurement can ride into an event payload without
-    a second clock read.
-    """
-
-    def __init__(self, clock=time.perf_counter):
-        self._clock = clock
-        self.seconds: dict[str, float] = {}
-        self.calls: dict[str, int] = {}
-        self._lock = threading.Lock()
-
-    def now(self) -> float:
-        return self._clock()
-
-    def add(self, phase: str, t0: float) -> float:
-        return self.record(phase, self._clock() - t0)
-
-    def record(self, phase: str, dt: float) -> float:
-        """Fold an externally-measured duration (an engine that already
-        metered the phase for its own stats hands the same value here,
-        instead of paying a second clock read).  This is the one telemetry
-        write the async drain thread performs, hence the lock."""
-        with self._lock:
-            self.seconds[phase] = self.seconds.get(phase, 0.0) + dt
-            self.calls[phase] = self.calls.get(phase, 0) + 1
-        return dt
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return {p: {"seconds": self.seconds[p], "calls": self.calls[p]}
-                    for p in self.seconds}
-
-    def reset(self) -> None:
-        with self._lock:
-            self.seconds.clear()
-            self.calls.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -344,10 +483,22 @@ class RequestRecord:
 # the engine-facing facade
 # ---------------------------------------------------------------------------
 
+def _compile_listener(ref):
+    """A ``jax.monitoring`` duration listener feeding the telemetry behind
+    the weak reference ``ref``: jax keeps its listeners for the life of
+    the process, and a listener must not keep a dropped telemetry alive."""
+    def on_event(event: str, duration: float, **kw) -> None:
+        tel = ref()
+        if tel is not None and event.startswith(COMPILE_EVENTS):
+            tel._compiled(event, duration, kw.get("fun_name", ""))
+    return on_event
+
+
 class Telemetry:
-    """Facade the serve engines drive: event trace + lifecycle records +
-    phase timers + latency percentile accumulators, behind one object so an
-    engine call site is a single ``if self.telemetry is not None`` guard.
+    """Facade the serve engines drive: event trace and spans + lifecycle
+    records + latency percentile accumulators + a compile counter, behind
+    one object so an engine call site is a single
+    ``if self.telemetry is not None`` guard (or one :func:`span`).
 
     Everything is host-side observation — no method here may dispatch
     device work or change what the engine computes.  The bit-identity
@@ -363,7 +514,6 @@ class Telemetry:
                  clock=time.perf_counter):
         self._clock = clock
         self.trace = EventTrace(capacity, clock=clock)
-        self.phases = PhaseTimers(clock=clock)
         self.ttft = Percentiles(percentile_window)
         self.tpot = Percentiles(percentile_window)
         self.queue_wait = Percentiles(percentile_window)
@@ -373,7 +523,13 @@ class Telemetry:
                          if profile_ticks > 0 else None)
         self._counters: dict[str, int] = {
             "requests_enqueued": 0, "requests_finished": 0,
-            "tokens_emitted": 0, "ticks": 0}
+            "tokens_emitted": 0, "ticks": 0, "compiles": 0}
+        self._tick = 0
+        # every jax compile stage while this telemetry lives, parented to
+        # the span open on the compiling thread ("which step recompiled")
+        from jax import monitoring
+        self._listener = _compile_listener(weakref.ref(self))
+        monitoring.register_event_duration_secs_listener(self._listener)
 
     # -- request lifecycle ------------------------------------------------
 
@@ -433,14 +589,26 @@ class Telemetry:
                         pages_held=rec.pages_held, drafted=rec.drafted,
                         accepted=rec.accepted)
 
-    # -- phases + generic events ------------------------------------------
+    # -- generic events + compiles ----------------------------------------
 
     def event(self, ev: str, tick: int, **fields) -> None:
         self.trace.emit(ev, tick, **fields)
 
+    def _compiled(self, event: str, seconds: float, fun) -> None:
+        """One compile stage (jaxpr trace, MLIR lowering, backend compile);
+        ``compiles`` counts backend compiles, one per executable built or
+        loaded from the persistent cache."""
+        stage = event[len(COMPILE_EVENTS):].removesuffix("_duration")
+        if stage == "backend_compile":
+            with self.trace._lock:
+                self._counters["compiles"] += 1
+        self.trace.emit("compile", self._tick, stage=stage, fun=str(fun),
+                        seconds=float(seconds), parent=self.trace.current())
+
     def tick_boundary(self, tick: int) -> None:
         """Called once at the top of every engine tick: counts ticks and
         drives the opt-in N-tick profiler window."""
+        self._tick = int(tick)
         self._counters["ticks"] += 1
         if self.profiler is not None:
             self.profiler.tick()
@@ -448,9 +616,9 @@ class Telemetry:
     # -- summaries ---------------------------------------------------------
 
     def summary(self) -> dict:
-        """The latency story in one dict: lifecycle counters, TTFT / TPOT /
-        queue-wait percentile summaries (seconds), and per-phase wall
-        accumulators."""
+        """The latency story in one dict: lifecycle and compile counters,
+        TTFT / TPOT / queue-wait percentile summaries (seconds), and the
+        lifetime ``{"seconds", "calls"}`` of each span kind."""
         return {**self._counters,
                 "inflight": len(self.live),
                 "records_dropped": self.records.dropped,
@@ -458,7 +626,7 @@ class Telemetry:
                 "ttft_s": self.ttft.summary(),
                 "tpot_s": self.tpot.summary(),
                 "queue_wait_s": self.queue_wait.summary(),
-                "phases": self.phases.snapshot()}
+                "phases": self.trace.totals()}
 
     def flush_jsonl(self, path) -> int:
         return self.trace.flush_jsonl(path)
@@ -467,8 +635,6 @@ class Telemetry:
         """Zero every accumulator (a bench/epoch boundary); the profiler —
         if any — keeps its window state."""
         self.trace.clear()
-        self.trace._seq = 0
-        self.phases.reset()
         self.ttft.reset()
         self.tpot.reset()
         self.queue_wait.reset()
@@ -478,8 +644,14 @@ class Telemetry:
             self._counters[k] = 0
 
     def close(self) -> None:
+        """Stop a still-open profiler window and the compile listener.
+        Idempotent."""
         if self.profiler is not None:
             self.profiler.stop()
+        if self._listener is not None:
+            from jax import monitoring
+            monitoring.unregister_event_duration_listener(self._listener)
+            self._listener = None
 
 
 class TickProfiler:

@@ -82,13 +82,24 @@ def mesh_for(mesh_shape):
     return _STATE[key]
 
 
+def _slotted(mesh_shape, **over) -> ServeEngine:
+    return ServeEngine(CFG, shared_params(), **engine_kwargs(**over),
+                       mesh=mesh_for(mesh_shape))
+
+
+def _paged(spec_k, mesh_shape, **over) -> PagedServeEngine:
+    kw = engine_kwargs(**{"page_size": PAGE, "num_pages": NUM_PAGES, **over})
+    if spec_k:
+        kw.update(spec_k=spec_k, spec_draft=WQ_DRAFT)
+    return PagedServeEngine(CFG, shared_params(), **kw,
+                            mesh=mesh_for(mesh_shape))
+
+
 def slotted_engine(mesh_shape=None, **over) -> ServeEngine:
     key = ("slotted", None if mesh_shape is None else tuple(mesh_shape),
            tuple(sorted(over.items())))
     if key not in _STATE:
-        _STATE[key] = ServeEngine(CFG, shared_params(),
-                                  **engine_kwargs(**over),
-                                  mesh=mesh_for(mesh_shape))
+        _STATE[key] = _slotted(mesh_shape, **over)
     return _STATE[key]
 
 
@@ -99,21 +110,19 @@ def paged_engine(spec_k: int = 0, mesh_shape=None, **over) -> PagedServeEngine:
     key = ("paged", spec_k, None if mesh_shape is None else tuple(mesh_shape),
            tuple(sorted(over.items())))
     if key not in _STATE:
-        kw = engine_kwargs(**{"page_size": PAGE, "num_pages": NUM_PAGES,
-                              **over})
-        if spec_k:
-            kw.update(spec_k=spec_k, spec_draft=WQ_DRAFT)
-        _STATE[key] = PagedServeEngine(CFG, shared_params(), **kw,
-                                       mesh=mesh_for(mesh_shape))
+        _STATE[key] = _paged(spec_k, mesh_shape, **over)
     return _STATE[key]
 
 
 def async_engine(kind: str = "slotted", spec_k: int = 0, mesh_shape=None,
                  *, drain_depth: int = 4, **over) -> AsyncServeEngine:
-    """Singleton async pipeline over the AOT-bucketed twin of a sync
-    engine singleton (ISSUE 10).  The wrapper reuses the underlying
-    engine's compile cache across traces exactly like the sync
-    singletons; ``run_trace`` works unchanged because the wrapper
+    """Singleton async pipeline over an AOT-bucketed twin of the sync
+    engine singletons.  The twin is the pipeline's own engine,
+    not the sync singleton with the same kwargs: the pipeline's scheduler
+    thread stays alive between traces and would race a test driving that
+    engine directly.  The wrapper reuses its engine's compile cache
+    across traces exactly like the sync singletons; ``run_trace`` works
+    unchanged because the wrapper
     delegates ``.tick`` and keeps ``run()`` as a compat shim.  The
     differential column compares this against the PLAIN (unbucketed,
     tick-loop) singletons, so one comparison covers both tentpole halves:
@@ -123,8 +132,8 @@ def async_engine(kind: str = "slotted", spec_k: int = 0, mesh_shape=None,
            drain_depth, tuple(sorted(over.items())))
     if key not in _STATE:
         over = dict(over, prefill_buckets=True)
-        eng = (slotted_engine(mesh_shape, **over) if kind == "slotted"
-               else paged_engine(spec_k, mesh_shape, **over))
+        eng = (_slotted(mesh_shape, **over) if kind == "slotted"
+               else _paged(spec_k, mesh_shape, **over))
         _STATE[key] = AsyncServeEngine(eng, drain_depth=drain_depth)
     return _STATE[key]
 

@@ -48,6 +48,19 @@ def test_async_run_reusable_across_traces():
             == H.run_trace(H.slotted_engine(), trace), f"seed {seed}"
 
 
+def test_async_run_returns_with_the_pipeline_drained():
+    """run() hands back its last completion only once the ticks still in
+    flight are harvested, so the caller may drive the wrapped engine
+    directly at once (the bucket-isolation differential test does): a
+    late harvest of a stale tick would retire the requests admitted
+    next, and a late dispatch would donate the cache under them."""
+    a = H.async_engine("slotted")
+    for seed in range(6):
+        H.run_trace(a, _trace(seed))
+        assert a._inflight == 0, f"seed {seed}"
+        assert a.engine.free_slots == a.engine.max_slots
+
+
 def test_async_duplicate_rid_rejected():
     trace = [((0, 1, 2), 4, 0), ((2, 1), 3, 0)]
     a = H.async_engine("slotted")

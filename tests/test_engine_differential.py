@@ -620,7 +620,7 @@ def test_async_telemetry_twin():
     """An instrumented async engine reproduces the plain sync engine's
     tokens (observation is never control flow, threads included) and its
     trace carries the same lifecycle events the sync instrumented engine
-    records — plus the pipeline's own dispatch/drain phase walls."""
+    records — plus the pipeline's own admission/flush/drain spans."""
     trace = random_greedy_trace(np.random.default_rng(4))
     a = H.async_engine("paged", telemetry=True)
     a.telemetry.reset()
@@ -630,7 +630,8 @@ def test_async_telemetry_twin():
     s = a.telemetry.summary()
     assert s["requests_finished"] == len(trace)
     assert s["ttft_s"]["count"] == len(trace)
-    assert {"dispatch", "drain", "decode", "admission"} <= set(s["phases"])
+    assert {"dispatch", "drain", "harvest", "decode_block", "admission",
+            "flush", "admission_wave"} <= set(s["phases"])
     kinds = {e["ev"] for e in a.telemetry.trace}
     assert {"enqueue", "admit", "first_token", "finish",
             "admission_wave", "decode_block"} <= kinds

@@ -4,7 +4,7 @@ Three layers of coverage:
 
 * **obs primitives** — ring-buffer bounding with drop accounting, event
   schema enforcement at emit time, JSONL flush format, percentile math
-  validated *exactly* against numpy, phase timers, request-record derived
+  validated *exactly* against numpy, span totals, request-record derived
   latencies on a fake clock, registry instruments and Prometheus text.
 * **engine integration** — a real paged+spec serve produces a
   schema-valid trace whose lifecycle events reconcile with the returned
@@ -25,8 +25,8 @@ import pytest
 import engine_harness as H
 from repro.launch.fidelity import FidelityMonitor, FidelityPolicy
 from repro.obs import (BoundedLog, EVENT_SCHEMA, EventTrace, MetricsRegistry,
-                       PhaseTimers, Percentiles, RequestRecord, SCHEMA_VERSION,
-                       Telemetry, TickProfiler)
+                       Percentiles, RequestRecord, SCHEMA_VERSION, Telemetry,
+                       TickProfiler)
 
 # ---------------------------------------------------------------------------
 # obs primitives
@@ -136,51 +136,84 @@ def test_percentiles_buffer_bounded_with_bench_scale_equality():
 
 
 def test_obs_accumulators_are_thread_safe():
-    """The async pipeline's drain thread folds phase walls while the
-    scheduler thread emits events and percentiles, and readers snapshot
-    mid-serve (ISSUE 10) — hammer every accumulator from threads and
-    assert nothing is lost (the pre-lock dict read-modify-write could
-    drop updates at bytecode boundaries)."""
+    """The async pipeline's drain thread closes spans while the scheduler
+    thread emits events and percentiles, and readers snapshot mid-serve:
+    hammer every accumulator from threads and assert nothing is lost (an
+    unlocked dict read-modify-write could drop updates at bytecode
+    boundaries), and that each thread's spans nest on its own stack."""
+    import sys
     import threading
-    timers = PhaseTimers()
     perc = Percentiles(window=128)
     trace = EventTrace(capacity=256)
-    N, T = 2000, 4
+    N, T = 2000, 6
     start = threading.Barrier(T)
+    bad = []
 
     def hammer(i):
         start.wait()
         for k in range(N):
-            timers.record("drain", 0.001)
-            perc.add(float(k))
-            trace.emit("enqueue", k, rid=i * N + k)
+            with trace.span("drain", k) as outer:
+                perc.add(float(k))
+                with trace.span("harvest", k, rids=[i], n_emitted=[k]):
+                    if trace.current() == outer.id:
+                        bad.append(i)        # not on this thread's stack
             if k % 256 == 0:                 # concurrent readers
-                timers.snapshot()
+                trace.totals()
                 perc.summary()
 
-    threads = [threading.Thread(target=hammer, args=(i,)) for i in range(T)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    snap = timers.snapshot()["drain"]
-    assert snap["calls"] == T * N
-    assert np.isclose(snap["seconds"], T * N * 0.001)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=hammer, args=(i,))
+                   for i in range(T)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(switch)
+    assert not bad
+    totals = trace.totals()
+    assert totals["drain"]["calls"] == totals["harvest"]["calls"] == T * N
     assert perc.count == T * N
     assert np.isclose(perc.total, T * sum(range(N)))
-    assert len(trace) + trace.dropped == T * N
+    assert len(trace) + trace.dropped == 2 * T * N
     seqs = [e["seq"] for e in trace]
     assert len(set(seqs)) == len(seqs), "racing emits burned a seq twice"
+    ids = [e["span"] for e in trace]
+    assert len(set(ids)) == len(ids), "racing spans shared an id"
+    for e in trace:
+        if e["ev"] == "harvest":
+            assert e["parent"] == e["span"] - 1   # its own thread's drain
 
 
 def test_phase_timers_accumulate():
-    clock = iter([0.0, 1.5, 2.0, 2.25]).__next__
-    t = PhaseTimers(clock=clock)
-    t.add("decode", t.now())             # 1.5
-    t.add("decode", t.now())             # 0.25
-    snap = t.snapshot()
-    assert snap["decode"]["calls"] == 2
-    assert np.isclose(snap["decode"]["seconds"], 1.75)
+    """Span totals on a fake clock: each closed span folds t - t0 into its
+    kind's lifetime seconds and calls, which ``summary()["phases"]``
+    reports in the shape the old phase timers had."""
+    clock = iter([0.0, 1.5, 2.0, 2.25, 3.0, 3.5, 4.0]).__next__
+    tel = Telemetry(clock=clock)
+    with tel.trace.span("dispatch", 0):          # 0.0 -> 1.5
+        pass
+    tick = tel.trace.open("decode_block", 0, n_active=1, block=2)   # 2.0
+    with tel.trace.span("dispatch", 0, parent=tick.id):   # 2.25 -> 3.0
+        pass
+    tel.trace.close(tick)                         # 3.5
+    snap = tel.summary()["phases"]
+    assert snap["dispatch"]["calls"] == 2
+    assert np.isclose(snap["dispatch"]["seconds"], 2.25)
+    assert snap["decode_block"] == {"seconds": 1.5, "calls": 1}
+    ev = list(tel.trace)
+    assert [e["ev"] for e in ev] == ["dispatch", "dispatch", "decode_block"]
+    assert ev[0]["parent"] is None and ev[1]["parent"] == tick.id
+    assert (ev[2]["t0"], ev[2]["t"]) == (2.0, 3.5)
+    with pytest.raises(RuntimeError):
+        with tel.trace.span("merge", 0):          # 4.0: a failed phase
+            raise RuntimeError("boom")
+    assert "merge" not in tel.summary()["phases"]
+    assert tel.trace.current() is None           # its stack entry is gone
+    tel.close()
 
 
 def test_request_record_derived_latencies():
@@ -366,7 +399,7 @@ def test_engine_per_request_acceptance_sums_to_totals():
     s = eng.telemetry.summary()
     assert s["ttft_s"]["count"] == len(trace)
     assert s["tpot_s"]["p99"] is not None
-    for phase in ("admission", "draft", "verify"):
+    for phase in ("admission_wave", "spec_draft", "spec_verify"):
         assert s["phases"][phase]["seconds"] > 0, phase
 
 
@@ -397,7 +430,7 @@ def test_slotted_engine_registry_and_trace():
             "admission_wave", "decode_block"} <= kinds
     for e in eng.telemetry.trace:
         if e["ev"] == "decode_block":
-            assert e["wall_s"] >= 0 and e["block"] == eng.decode_block
+            assert e["t"] >= e["t0"] and e["block"] == eng.decode_block
 
 
 def test_spec_draft_seconds_uses_monotonic_clock():
@@ -413,7 +446,7 @@ def test_spec_draft_seconds_uses_monotonic_clock():
     assert "perf_counter" in src
     eng, _, _ = _served_telemetry()
     assert eng.spec_draft_seconds >= 0
-    assert eng.telemetry.phases.seconds["draft"] >= 0
+    assert eng.telemetry.summary()["phases"]["spec_draft"]["seconds"] >= 0
 
 
 # ---------------------------------------------------------------------------
