@@ -3,9 +3,10 @@
 Unlike the dense attention wrappers there is no block-size fallback to
 pick: the page *is* the KV block, so any page size works as-is (odd sizes
 included — masking, not padding, handles partially-filled tail pages).
-The wrapper upcasts fp pools to f32 (matching the production attention
-paths, which compute scores in f32); quantized pools stay int8 all the
-way into the kernel, which dequantizes one page tile at a time.  Raw
+Pools reach the kernel in their stored dtype — a cast here would copy the
+whole pool — and the kernel computes scores with f32 accumulation;
+quantized pools stay int8 all the way in, dequantized one block at a
+time in VMEM.  Raw
 block tables flow through unchanged: entries outside ``[0, num_pages)``
 are the unmapped-block sentinel, and both the kernel and the ref oracle
 mask those pages out of the softmax (the read-side mirror of the write
@@ -49,13 +50,14 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         q = q[:, :, None]
     bt = block_tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
-    if kv_quant is None:
-        k_pages = k_pages.astype(jnp.float32)
-        v_pages = v_pages.astype(jnp.float32)
-    fn = paged_attention_ref if use_ref else paged_attention_kernel
-    kw = {} if use_ref else {"interpret": interpret}
-    out = fn(q.astype(jnp.float32), k_pages, v_pages, bt, lengths,
-             k_scale=k_scale, v_scale=v_scale, kv_quant=kv_quant, **kw)
+    if use_ref:
+        out = paged_attention_ref(q.astype(jnp.float32), k_pages, v_pages,
+                                  bt, lengths, k_scale=k_scale,
+                                  v_scale=v_scale, kv_quant=kv_quant)
+    else:
+        out = paged_attention_kernel(q, k_pages, v_pages, bt, lengths,
+                                     k_scale=k_scale, v_scale=v_scale,
+                                     kv_quant=kv_quant, interpret=interpret)
     out = out.astype(q.dtype)
     return out[:, :, 0] if squeeze else out
 

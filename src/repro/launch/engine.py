@@ -86,6 +86,7 @@ from ..core import drift as drift_lib
 from ..core.engine import NLDPEConfig, OFF
 from ..models import lm
 from ..models.lm import ATTN_TYPES
+from ..nn.attention import paged_kernel_path
 from ..obs import MetricsRegistry, Telemetry, span
 from ..parallel import sharding
 from ..parallel.context import sharding_ctx
@@ -826,7 +827,8 @@ class ServeEngine:
             tel.tick_boundary(self.tick)
             tick = tel.trace.open(
                 "decode_block", self.tick, block=self.decode_block,
-                n_active=sum(o is not None for o in self._slot_owner))
+                n_active=sum(o is not None for o in self._slot_owner),
+                **self._tick_attrs())
         with span(tel, "dispatch", self.tick, parent=tick and tick.id):
             (self.cache, self._tok, self._pos, self._active, self._gen_left,
              emits) = self._decode_fn(self.params, self.cache, self._tok,
@@ -838,6 +840,11 @@ class ServeEngine:
         if tick is not None:
             tick.tick = self.tick
         return emits, active, tick
+
+    def _tick_attrs(self) -> dict:
+        """Page-read attributes of a tick's ``decode_block`` span: none
+        for the slotted cache (the paged engine counts its reads)."""
+        return {"kv_blocks_read": None, "kv_blocks_view": None}
 
     def step(self) -> list[Completion]:
         """One decode tick: ``decode_block`` scanned steps over all slots.
@@ -979,13 +986,15 @@ class PagedServeEngine(ServeEngine):
     original.  Shared pages are therefore read-only by construction and
     the jit'd compute is oblivious to sharing.
 
-    Determinism contract: outputs are **bit-exact** with the slotted
+    Determinism contract (CPU): outputs are **bit-exact** with the slotted
     ``ServeEngine`` on any trace, OFF and NL-DPE-fused — attention runs on
     the gathered dense view (``nn.attention.paged_dense_view``), which
     reproduces the slotted cache's score rows exactly (prefix-hit pages
     hold bit-identical K/V because K/V at a position depend only on the
     token prefix and the exp-grid anchors to the fixed cache length; see
-    DESIGN.md §7 and tests/test_paged_engine*.py).
+    DESIGN.md §7 and tests/test_paged_engine*.py).  On a TPU, OFF-mode
+    attention reads each slot's live pages through
+    ``kernels/paged_attention`` instead and matches within float tolerance.
 
     **Speculative decoding** (``spec_k > 0``, DESIGN.md §8): each decode
     tick drafts ``spec_k`` tokens per slot through the NL-DPE
@@ -1060,6 +1069,13 @@ class PagedServeEngine(ServeEngine):
                          batch_groups=batch_groups,
                          kv_quant=kv_quant, mesh=mesh, rules=rules,
                          telemetry=telemetry, prefill_buckets=prefill_buckets)
+        # the platform picks the attention path (nn.attention): with the
+        # kernel each slot reads only its live pages, counted per tick from
+        # host state — each owned slot's position is its seat position
+        # plus the steps dispatched since (``_pos_base`` + ``tick``)
+        self._live_page_reads = self._ctx(paged_kernel_path)(
+            nldpe, lm._attn_spec(self.cfg, "attn"))
+        self._pos_base = np.zeros((max_slots,), np.int64)
         self._setup_fn = jax.jit(self._ctx(self._build_setup_fn()),
                                  donate_argnums=(0,))
         self._copy_fn = jax.jit(self._ctx(self._build_copy_fn()),
@@ -1314,6 +1330,25 @@ class PagedServeEngine(ServeEngine):
             tel.event("fidelity", self.tick, kind=action,
                       spec_k=self.monitor.spec_k, ewma=self.monitor.ewma,
                       vclock_s=self.vclock)
+
+    def _seat(self, reqs, slots, *rest) -> list[Completion]:
+        for r, sl in zip(reqs, slots):
+            self._pos_base[sl] = len(r.tokens) - self.tick
+        return super()._seat(reqs, slots, *rest)
+
+    def _tick_attrs(self) -> dict:
+        """With the kernel path: ``kv_blocks_read``, the pages the tick's
+        last step reads, Σ over owned slots of ceil((pos + decode_block) /
+        page_size); ``kv_blocks_view``, the pages the dense view would
+        gather, slots × blocks.  Host arithmetic only, no device read."""
+        if not self._live_page_reads:
+            return super()._tick_attrs()
+        end = self.tick + self.decode_block
+        read = sum(-(-min(int(self._pos_base[sl]) + end, self.max_len)
+                     // self.page_size)
+                   for sl, r in enumerate(self._slot_owner) if r is not None)
+        return {"kv_blocks_read": read,
+                "kv_blocks_view": self.max_slots * self.n_blocks}
 
     def _dispatch_tick(self):
         """One decode tick's dispatch.  Non-speculative engines scan
@@ -1759,6 +1794,7 @@ class PagedServeEngine(ServeEngine):
             jnp.asarray(n_temp), jnp.asarray(n_topk), jnp.asarray(n_keys))
         self._slot_pages[sl] = list(fresh)
         self._slot_owner[sl] = pre.req
+        self._pos_base[sl] = pre.pos - self.tick
         self.resumes += 1
         tel = self.telemetry
         if tel is not None:

@@ -320,9 +320,9 @@ def _parser() -> argparse.ArgumentParser:
                         "position) scales for --paged: 'log8' = the "
                         "drafter's sign-magnitude log grid (DESIGN.md "
                         "§11), 'int8' = uniform absmax grid.  ~3.5x pool "
-                        "capacity at the same HBM; with "
-                        "NLDPE_PAGED_KERNEL=1 the Pallas kernel "
-                        "dequantizes per page tile in VMEM")
+                        "capacity at the same HBM; on a TPU the Pallas "
+                        "paged-attention kernel dequantizes each block "
+                        "of pages in VMEM")
     p.add_argument("--slots", type=int, default=4,
                    help="KV-cache slots for --continuous/--paged")
     p.add_argument("--requests", type=int, default=12,

@@ -9,7 +9,9 @@ Three compute paths chosen by shape/mode:
   lowers).
 * ``banded``   — sliding-window layers (gemma3 local, recurrentgemma):
   per-Q-block dynamic slice of the KV band -> O(S * window) compute.
-* ``decode``   — single-token step against a (possibly ring-buffered) cache.
+* ``decode``   — single-token step against a (possibly ring-buffered) cache;
+  paged caches on a TPU read their live pages through
+  kernels/paged_attention instead (``paged_kernel_path``).
 
 GQA is computed grouped ('bkgqd,bkld->bkgql'), never materializing repeated
 KV heads.  NL-DPE numerics route through core.attention.nldpe_attention
@@ -311,8 +313,9 @@ def init_paged_cache(s: AttnSpec, batch: int, max_len: int, *,
 def paged_dense_view(cache) -> dict:
     """Gather a paged cache into the dense slotted layout (B, H, L, D).
 
-    This is the lax twin of ``kernels/paged_attention`` (which gathers
-    page-by-page inside the Pallas grid): pages are taken through the block
+    This is the lax twin of ``kernels/paged_attention`` (which copies a
+    slot's live pages inside the Pallas grid, the TPU path): the CPU path
+    and the kernel's test reference.  Pages are taken through the block
     table in block order, so logical position ``p`` lands at row ``p`` of
     the view — making every downstream op (``cached_attention``, the
     NL-DPE log-domain paths) bit-identical to the dense slotted cache,
@@ -580,18 +583,50 @@ def _update_cache_paged(cache, k_new, v_new, pos: jax.Array, write_mask=None,
     return out
 
 
+def _platform() -> str:
+    """Platform the traced computation runs on: the ambient mesh's devices
+    under a sharding context, else JAX's default backend."""
+    from ..parallel.context import current as _sharding_context
+    ctx = _sharding_context()
+    if ctx is not None:
+        return ctx[0].devices.flat[0].platform
+    return jax.default_backend()
+
+
+def paged_kernel_path(nldpe: NLDPEConfig, s: AttnSpec) -> bool:
+    """Whether paged decode/chunk attention reads K/V through
+    ``kernels/paged_attention`` (each slot's live pages only) rather than
+    the gathered ``paged_dense_view``.
+
+    The kernel covers plain softmax attention: NL-DPE numerics, logit
+    softcaps and windowed layers keep the dense view.  Within those gates
+    the platform decides — a TPU takes the kernel; every other platform
+    keeps the dense view, bit-identical to the slotted cache.
+    ``NLDPE_PAGED_KERNEL=1`` forces the kernel (interpreted off-TPU) where
+    the platform would not choose it, for differential tests."""
+    if nldpe.enabled or s.softcap is not None or s.window is not None:
+        return False
+    if os.environ.get("NLDPE_PAGED_KERNEL", "0") not in ("", "0"):
+        return True
+    return _platform() == "tpu"
+
+
 def _paged_kernel_dispatch(cache, q: jax.Array, lengths: jax.Array,
-                           kv_quant: str | None = None):
-    """Route the NLDPE_PAGED_KERNEL opt-in through the Pallas kernel —
-    per-shard under ``shard_map`` when an ambient sharding context is
-    installed (GSPMD cannot partition a ``pallas_call``), plain otherwise.
+                           kv_quant: str | None = None, write_mask=None):
+    """Route paged attention through the Pallas kernel — per-shard under
+    ``shard_map`` when an ambient sharding context is installed (GSPMD
+    cannot partition a ``pallas_call``), plain otherwise.
     ``q`` is (B, Hq, D) decode or (B, Hq, Q, D) chunk/verify queries.
     Quantized pools hand the kernel the raw int8 code pools plus their
-    scales — dequantization happens per page tile inside the grid, so the
-    fp pool is never materialized."""
+    scales — dequantization happens per block inside the grid, so the fp
+    pool is never materialized.  Slots ``write_mask`` freezes attend to
+    one position: their outputs are discarded, so the kernel reads at
+    most one page for them instead of their stale lengths."""
     from ..kernels.paged_attention.ops import (paged_attention,
                                                paged_attention_sharded)
     from ..parallel.context import current as _sharding_context
+    if write_mask is not None:
+        lengths = jnp.where(write_mask, lengths, 1)
     ks, vs = cache.get("k_scale"), cache.get("v_scale")
     kv_quant = (kv_quant or "int8") if ks is not None else None
     ctx = _sharding_context()
@@ -712,29 +747,27 @@ def attn_apply(p, s: AttnSpec, x: jax.Array, *, positions: jax.Array,
             pos = positions[0]
         cache = update_cache(cache, k, v, pos, write_mask=write_mask,
                              kv_quant=s.kv_quant)
-        if ("bt" in cache and pos.ndim == 1
-                and not nldpe.enabled and s.softcap is None
-                and os.environ.get("NLDPE_PAGED_KERNEL", "0")
-                not in ("", "0")):
-            # opt-in TPU hot path: stream pages through the Pallas kernel
+        if "bt" in cache and pos.ndim == 1 and paged_kernel_path(nldpe, s):
+            # stream each slot's live pages through the Pallas kernel
             # (block-table gather inside the grid) instead of materializing
-            # the dense view — quantized pools dequantize per page tile in
+            # the dense view — quantized pools dequantize per block in
             # VMEM.  Matches the dense path within float tolerance, not
-            # bitwise — hence the explicit switch; engine caches are
-            # contiguous, so valid lanes are [0, pos] per slot.  Under an
-            # ambient mesh the kernel dispatches per-shard via shard_map
-            # (GSPMD cannot partition a pallas_call), block table
-            # replicated across the model axis (DESIGN.md §9).
+            # bitwise; engine caches are contiguous, so valid lanes are
+            # [0, pos] per slot.  Under an ambient mesh the kernel
+            # dispatches per-shard via shard_map (GSPMD cannot partition a
+            # pallas_call), block table replicated across the model axis
+            # (DESIGN.md §9).
             o = _paged_kernel_dispatch(cache, q[:, :, 0],
                                        pos.astype(jnp.int32) + 1,
-                                       kv_quant=s.kv_quant)[:, :, None]
+                                       kv_quant=s.kv_quant,
+                                       write_mask=write_mask)[:, :, None]
             o = shard(o, "batch", "heads", None, None)
             o = shard(o, "batch", "o_heads", None, None)
             y = jnp.einsum("bhsk,hkd->bsd", o, p["wo"].astype(o.dtype))
             return shard(y, "batch", None, "act_embed"), cache
-        # paged caches attend through the gathered dense view: bit-identical
-        # to the slotted layout (the lax twin of kernels/paged_attention;
-        # NLDPE_PAGED_KERNEL=1 above opts decode into the kernel itself)
+        # elsewhere paged caches attend through the gathered dense view:
+        # bit-identical to the slotted layout (the lax twin of
+        # kernels/paged_attention)
         att = paged_dense_view(cache) if "bt" in cache else cache
         if nldpe.enabled:
             # NL-DPE decode: log-domain DMMul over the cached keys/values,
@@ -754,20 +787,18 @@ def attn_apply(p, s: AttnSpec, x: jax.Array, *, positions: jax.Array,
                 else jnp.broadcast_to(positions[None, :], (b, seq)))
         cache = update_cache(cache, k, v, qpos, write_mask=write_mask,
                              kv_quant=s.kv_quant)
-        if ("bt" in cache and not nldpe.enabled and s.softcap is None
-                and os.environ.get("NLDPE_PAGED_KERNEL", "0")
-                not in ("", "0")):
-            # opt-in TPU hot path, q_len > 1: chunk queries sit at
-            # consecutive per-slot offsets (suffix prefill and the
-            # speculative verify pass both write the chunk's K/V first),
-            # so query i of slot b attends to [0, qpos[b, 0] + i] — the
-            # kernel's ragged staircase with base lengths = qpos[:, 0]+1.
-            # Same float-tolerance and shard_map notes as the decode
-            # opt-in below.
+        if "bt" in cache and paged_kernel_path(nldpe, s):
+            # the kernel path, q_len > 1: chunk queries sit at consecutive
+            # per-slot offsets (suffix prefill and the speculative verify
+            # pass both write the chunk's K/V first), so query i of slot b
+            # attends to [0, qpos[b, 0] + i] — the kernel's ragged
+            # staircase with base lengths = qpos[:, 0] + 1.  Same
+            # float-tolerance and shard_map notes as decode above.
             lengths = jnp.clip(qpos[:, 0].astype(jnp.int32) + 1, 1,
                                cache["pos"].shape[1])
             o = _paged_kernel_dispatch(cache, q, lengths,
-                                       kv_quant=s.kv_quant)
+                                       kv_quant=s.kv_quant,
+                                       write_mask=write_mask)
             o = shard(o, "batch", "heads", None, None)
             o = shard(o, "batch", "o_heads", None, None)
             y = jnp.einsum("bhsk,hkd->bsd", o, p["wo"].astype(o.dtype))

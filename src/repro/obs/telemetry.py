@@ -70,7 +70,10 @@ import numpy as np
 #: ``wall_s`` payload (duration is ``t - t0``); new kinds "submit",
 #: "compile" and the spans "admission", "flush", "plan", "prefill",
 #: "first_token_sync", "merge", "dispatch", "drain", "harvest".
-SCHEMA_VERSION = 3
+#: v4: "decode_block" carries ``kv_blocks_read``/``kv_blocks_view``, the
+#: pages paged attention reads in the tick and the pages a dense view
+#: would gather (None where attention does not read pages in place).
+SCHEMA_VERSION = 4
 
 #: Fields every span carries besides its payload: its start (``t`` is its
 #: end, both on the trace clock), its id, and the id of the span that
@@ -107,7 +110,8 @@ EVENT_SCHEMA: dict[str, tuple[str, ...]] = {
     "merge": _span(),
     # decode tick (cross-thread: dispatch -> harvest) and its parts;
     # harvest carries each request's tokens received (emission stamps)
-    "decode_block": _span("n_active", "block"),
+    "decode_block": _span("n_active", "block", "kv_blocks_read",
+                          "kv_blocks_view"),
     "dispatch": _span(),
     "drain": _span(),
     "harvest": _span("rids", "n_emitted"),

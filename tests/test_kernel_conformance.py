@@ -61,6 +61,14 @@ PAGED_MQ_SHAPES = [(1, 2, 2, 8, 2, 8, 8, 2), (2, 4, 2, 12, 3, 16, 16, 3),
 # chunk-prefill staircase, odd page sizes x GQA groups (DESIGN.md §11)
 PAGED_QUANT_SHAPES = [(1, 2, 2, 8, 2, 8, 8, 1), (2, 4, 2, 12, 3, 16, 16, 3),
                       (1, 4, 1, 9, 3, 6, 32, 5), (2, 2, 1, 10, 4, 5, 8, 2)]
+# (B, Hq, Hkv, P, NB, ps, D, q_len, pool): several compute blocks of
+# several pages per sequence (128-token pages: 4 a block), one sequence
+# each at length 1, one short of a block, exactly a block and the most the
+# table holds; G in {1, 8}, q_len in {1, 16}, fp and quantized pools
+PAGED_BLOCK_SHAPES = [(4, 8, 1, 48, 10, 128, 8, 1, None),
+                      (4, 2, 2, 48, 10, 128, 8, 16, None),
+                      (4, 8, 1, 48, 10, 128, 8, 16, "log8"),
+                      (4, 2, 2, 48, 10, 128, 8, 1, "int8")]
 
 
 def _rand(shape, dtype, scale=1.0):
@@ -205,6 +213,83 @@ def _paged_quant_case(shape, mode):
     return Case(f"paged_{mode}", shape, run)
 
 
+def _block_lengths(b, hkv, nb, ps, d, itemsize, rows, ql):
+    """Lengths 1, one short of a compute block, a block, and the most the
+    table holds (the staircase's last query at its last position)."""
+    from repro.kernels.paged_attention.kernel import block_pages
+    bk = block_pages(ps, hkv, d, itemsize, rows, nb) * ps
+    assert nb * ps > 2 * bk, "want several blocks per sequence"
+    return np.asarray([1, bk - 1, bk, nb * ps - ql + 1][:b], np.int32)
+
+
+def _block_pools(shape, dtype, poison=False):
+    """Pools, a block table of distinct pages whose entries past each
+    sequence's live pages are sentinels (``num_pages`` or -1), and the
+    lengths of ``_block_lengths``.  ``poison`` instead maps those entries
+    to real pages and fills every position past a sequence's last visible
+    one (``lengths + q_len - 1``) with NaN — in quantized pools the scales
+    of every page past the live ones — and also returns the clean pools."""
+    b, hq, hkv, p, nb, ps, d, ql, mode = shape
+    q = _rand((b, hq, ql, d), dtype)
+    if mode is None:
+        kp, vp = _rand((p, hkv, ps, d), dtype), _rand((p, hkv, ps, d), dtype)
+        pools, item = dict(k=kp, v=vp), jnp.dtype(dtype).itemsize
+    else:
+        kq, ks = _quantize_kv(_rand((p, hkv, ps, d), jnp.float32), mode)
+        vq, vs = _quantize_kv(_rand((p, hkv, ps, d), jnp.float32), mode)
+        pools, item = dict(k=kq, v=vq, k_scale=ks, v_scale=vs), 1
+    lengths = _block_lengths(b, hkv, nb, ps, d, item, (hq // hkv) * ql, ql)
+    live = -(-(lengths + ql - 1) // ps)
+    bt = RNG.permutation(p)[:b * nb].reshape(b, nb).astype(np.int32)
+    dead = np.arange(nb)[None] >= live[:, None]
+    if not poison:
+        bt[dead] = np.where(RNG.random(dead.sum()) < 0.5, p, -1)
+    clean = dict(pools)
+    if poison:
+        for r in range(b):
+            seen = lengths[r] + ql - 1
+            for blk in range(nb):
+                page = bt[r, blk]
+                off = np.arange(ps) + blk * ps
+                if mode is None:
+                    for name in ("k", "v"):
+                        pools[name] = pools[name].at[page, :, off >= seen] \
+                            .set(jnp.nan)
+        if mode is not None:      # scales of the pages no sequence reads
+            unread = np.setdiff1d(np.arange(p), bt[~dead])
+            for name in ("k_scale", "v_scale"):
+                pools[name] = pools[name].at[unread].set(jnp.nan)
+    return q, pools, clean, jnp.asarray(bt), jnp.asarray(lengths)
+
+
+def _paged_block_case(shape):
+    mode = shape[-1]
+
+    def run(dtype):
+        q, pools, _, bt, lengths = _block_pools(shape, dtype)
+        kw = {} if mode is None else dict(
+            k_scale=pools["k_scale"], v_scale=pools["v_scale"],
+            kv_quant=mode)
+        return (paged_attention(q, pools["k"], pools["v"], bt, lengths, **kw),
+                paged_attention(q, pools["k"], pools["v"], bt, lengths,
+                                use_ref=True, **kw), 0.0)
+    return Case(f"paged_blocks_{mode or 'fp'}", shape[:-1], run)
+
+
+def _paged_poison_case(shape):
+    """Every position past each sequence's last visible one holds NaN:
+    the kernel's output is finite and equals the reference on the clean
+    pool, so nothing past a length reaches the result."""
+    def run(dtype):
+        q, pools, clean, bt, lengths = _block_pools(shape, dtype,
+                                                    poison=True)
+        out = paged_attention(q, pools["k"], pools["v"], bt, lengths)
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+        return (out, paged_attention(q, clean["k"], clean["v"], bt, lengths,
+                                     use_ref=True), 0.0)
+    return Case("paged_poisoned_tail", shape[:-1], run)
+
+
 CASES = (
     [_crossbar_case(s) for s in MATMUL_SHAPES]
     + [_qmatmul_case(s) for s in MATMUL_SHAPES]
@@ -216,6 +301,8 @@ CASES = (
     + [_paged_mq_case(s) for s in PAGED_MQ_SHAPES]
     + [_paged_quant_case(s, m) for s in PAGED_QUANT_SHAPES
        for m in ("log8", "int8")]
+    + [_paged_block_case(s) for s in PAGED_BLOCK_SHAPES]
+    + [_paged_poison_case(PAGED_BLOCK_SHAPES[1])]
 )
 
 

@@ -324,3 +324,81 @@ def test_stats_expose_prefix_metrics(params):
                 "cow_forks", "published"):
         assert key in st
     assert st["lookups"] == 6
+
+
+def _paged_attention_jaxprs(spec):
+    """The jaxprs of one paged decode and one paged chunk ``attn_apply``."""
+    from repro.nn import attention
+    p = attention.attn_init(jax.random.key(1), spec)
+    cache = attention.init_paged_cache(spec, 2, 16, num_pages=8,
+                                       page_size=4, dtype=jnp.float32)
+    x1 = jnp.zeros((2, 1, spec.d_model), jnp.float32)
+    x4 = jnp.zeros((2, 4, spec.d_model), jnp.float32)
+    pos = jnp.asarray([[3], [5]], jnp.int32)
+    chunk = pos + jnp.arange(4, dtype=jnp.int32)
+    return [str(jax.make_jaxpr(lambda c, x, q: attention.attn_apply(
+        p, spec, x, positions=q, mode=mode, cache=c))(cache, x, q))
+        for mode, x, q in (("decode", x1, pos), ("chunk", x4, chunk))]
+
+
+def test_paged_attention_path_follows_the_platform(monkeypatch):
+    """The platform chooses the paged attention path: on the CPU decode
+    and chunk attention gather the dense view (no kernel in the program);
+    with the backend seen as a TPU they read pages through the Pallas
+    kernel — unless NL-DPE numerics, a softcap or a window keep the view.
+    NLDPE_PAGED_KERNEL=1 forces the kernel where the platform would not."""
+    from repro.nn import attention
+    spec = lm._attn_spec(CFG, "attn")
+    monkeypatch.delenv("NLDPE_PAGED_KERNEL", raising=False)
+    assert not attention.paged_kernel_path(NLDPEConfig(enabled=False), spec)
+    for text in _paged_attention_jaxprs(spec):
+        assert "pallas_call" not in text and "gather" in text
+    monkeypatch.setenv("NLDPE_PAGED_KERNEL", "1")
+    assert attention.paged_kernel_path(NLDPEConfig(enabled=False), spec)
+    monkeypatch.delenv("NLDPE_PAGED_KERNEL")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention.paged_kernel_path(NLDPEConfig(enabled=False), spec)
+    for text in _paged_attention_jaxprs(spec):
+        assert "pallas_call" in text
+    assert not attention.paged_kernel_path(FUSED, spec)
+    for gate in (dict(softcap=30.0), dict(window=8)):
+        assert not attention.paged_kernel_path(
+            NLDPEConfig(enabled=False), dataclasses.replace(spec, **gate))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["view", "kernel"])
+def test_decode_ticks_count_the_pages_attention_reads(params, monkeypatch,
+                                                      kernel):
+    """Each tick's ``decode_block`` span carries the pages the kernel
+    reads in the tick's last step — Σ over owned slots of ceil((pos +
+    decode_block) / page_size), ``pos`` from the prompt and the tokens
+    harvested before the tick — beside the dense view's slots × blocks;
+    both None where attention gathers the view (the CPU default)."""
+    if kernel:
+        monkeypatch.setenv("NLDPE_PAGED_KERNEL", "1")
+    else:
+        monkeypatch.delenv("NLDPE_PAGED_KERNEL", raising=False)
+    slots, ps, block = 2, 4, 2
+    paged = PagedServeEngine(CFG, params, max_slots=slots, max_len=MAX_LEN,
+                             prefill_chunk=4, decode_block=block,
+                             page_size=ps, telemetry=True)
+    reqs = [Request(rid=0, tokens=tuple(range(1, 6)), max_new_tokens=7),
+            Request(rid=1, tokens=tuple(range(1, 10)), max_new_tokens=4)]
+    plen = {r.rid: len(r.tokens) for r in reqs}
+    paged.run(reqs)
+    events = list(paged.telemetry.trace)
+    ticks = {e["span"]: e for e in events if e["ev"] == "decode_block"}
+    assert ticks
+    if not kernel:
+        assert all(e["kv_blocks_read"] is None and e["kv_blocks_view"] is None
+                   for e in ticks.values())
+        return
+    emitted = {rid: 1 for rid in plen}          # the wave's first tokens
+    for h in (e for e in events if e["ev"] == "harvest"):
+        tick = ticks[h["parent"]]
+        want = sum(-(-min(plen[r] + emitted[r] - 1 + block, MAX_LEN) // ps)
+                   for r in h["rids"])
+        assert tick["kv_blocks_read"] == want, (tick, h)
+        assert tick["kv_blocks_view"] == slots * (MAX_LEN // ps)
+        for r, n in zip(h["rids"], h["n_emitted"]):
+            emitted[r] += n

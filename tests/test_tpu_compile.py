@@ -78,6 +78,26 @@ def test_paged_attention_compiles(one_chip, kv_quant, q_len):
     _compile(fn, one_chip, *shapes)
 
 
+# the benchmark cells' engines: (slots, blocks, pages, q heads, kv heads,
+# head_dim) — Qwen2.5-3B (G 8) and MiniCPM-2B (MHA, G 1, head_dim 64)
+CELL_SHAPES = {"qwen2_5_3b": (32, 256, 7168, HQ, HKV, HEAD),
+               "minicpm_2b": (8, 192, 1536, 36, 36, 64)}
+
+
+@pytest.mark.parametrize("q_len", [1, 16], ids=["decode", "chunk16"])
+@pytest.mark.parametrize("model", sorted(CELL_SHAPES))
+def test_paged_attention_compiles_at_cell_shapes(one_chip, model, q_len):
+    """The kernel the paged engine runs on a TPU, at the benchmark cells'
+    engine shapes: bf16 pools of 16-token pages, decode and a 16-row
+    chunk."""
+    slots, nb, pages, hq, hkv, head = CELL_SHAPES[model]
+    q = (slots, hq, head) if q_len == 1 else (slots, hq, q_len, head)
+    pool = (pages, hkv, PAGE, head)
+    _compile(functools.partial(paged_attention, interpret=False), one_chip,
+             (q, jnp.bfloat16), (pool, jnp.bfloat16), (pool, jnp.bfloat16),
+             ((slots, nb), jnp.int32), ((slots,), jnp.int32))
+
+
 def test_fused_linear_acam_compiles(one_chip):
     fn = functools.partial(fused_linear_acam, act="silu", interpret=False)
     _compile(fn, one_chip, ((16, D_MODEL), jnp.bfloat16),
