@@ -12,7 +12,11 @@ A run:
 1. set-up (``setup_s`` counts all of it, from process start): the
    benchmark's weights from the seed (``weights.py``), the program's paged
    engine as ``launch/serve.build_paged_engine`` builds it for
-   ``--paged --published-width --telemetry``, wrapped in
+   ``--paged --published-width --telemetry`` (on a ``("data", "model")``
+   mesh of the cell's chips where the configuration's ``engine`` block
+   gives ``"mesh": [data, model]``, under the program's default rules,
+   with every weight made straight into the placement the engine gives
+   it), wrapped in
    ``AsyncServeEngine``, and a warm-up that runs every program the window
    drives (chunk prefill, a copy-on-write fork, first-token sampling,
    decode) so that nothing compiles in the window;
@@ -126,7 +130,8 @@ class Run:
     per decode tick as its tokens reach the host, ``(t, emitted,
     attended, steps)``; ``events``: the program's telemetry events;
     ``profile``: the traced stretch (start, end) and ``reduced`` its
-    ``xplane.Reduced``; ``peaks``: the chip's row of ``peaks.json``.
+    ``xplane.Reduced``; ``peaks``: the chip's row of ``peaks.json``;
+    ``chips``: the devices the cell runs on.
     """
 
     def __init__(self, **kw):
@@ -142,6 +147,10 @@ class Run:
 
     def window_s(self) -> float:
         return self.window[1] - self.window[0]
+
+    def peak(self, name: str) -> float:
+        """A peak of ``peaks.json`` over all the chips the cell runs on."""
+        return self.chips * self.peaks[name]
 
     def due_in_window(self) -> list[int]:
         return [rid for rid, t in self.due.items() if self.in_window(t)]
@@ -183,6 +192,37 @@ def _device_check(rehearse: bool, chips: int):
             raise NoChip(f"the cell needs {chips} chips, JAX sees "
                          f"{len(devs)}")
     return devs[:chips]
+
+
+def mesh_shape(cell: dict, cfg: dict) -> tuple[int, int] | None:
+    """The configuration's ``(data, model)`` mesh, or None for one device;
+    a cell whose chips differ from the mesh's devices is refused."""
+    mesh = cfg["engine"].get("mesh")
+    devices = 1 if mesh is None else mesh[0] * mesh[1]
+    if cell.get("chips", 1) != devices:
+        raise ValueError(f"the cell asks for {cell.get('chips', 1)} chips "
+                         f"and its engine's mesh {mesh} for {devices}")
+    return None if mesh is None else (int(mesh[0]), int(mesh[1]))
+
+
+def _mesh(devs, shape):
+    from jax.sharding import AxisType, Mesh
+    return Mesh(np.asarray(devs).reshape(shape), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def _param_shardings(pcfg, mesh, rules):
+    """Where the engine places each weight: the program's parameter specs
+    under its rules on the mesh."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.models import lm
+    from repro.nn.module import spec_mode
+    with spec_mode(mesh, rules):
+        specs = lm.init_params(jax.random.key(0), pcfg)
+    return jax.tree.map(lambda p: NamedSharding(mesh, p), specs,
+                        is_leaf=lambda x: isinstance(x, PartitionSpec))
 
 
 def _check_layout(params, pcfg) -> None:
@@ -287,6 +327,7 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
     scale = cfg.get("length_scale", 1.0)
     if bench is None:
         bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    shape = mesh_shape(cell, cfg)
     devs = _device_check(rehearse, cell.get("chips", 1))
     if str(REPO / "src") not in sys.path:    # the program under test
         sys.path.append(str(REPO / "src"))
@@ -294,11 +335,13 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
     import jax
     import jax.numpy as jnp
 
+    from repro.configs import get_config
     from repro.core.engine import NLDPEConfig
     from repro.launch import serve
     from repro.launch.async_engine import AsyncServeEngine
     from repro.launch.engine import Completion, Request
     from repro.obs import Telemetry
+    from repro.parallel.sharding import serve_exact_rules
     from repro.runtime.compile_cache import enable_compile_cache
 
     # every trace and compile the process makes, with the host time it
@@ -316,8 +359,14 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
     peaks = None if rehearse else model_math.peaks(devs[0].device_kind)
 
     # -- set-up ---------------------------------------------------------
+    pcfg = get_config(cfg["arch"], reduced=rehearse)
+    _check_config(cfg, pcfg)
+    mesh = placed = None
+    if shape is not None:
+        mesh = _mesh(devs, shape)
+        placed = _param_shardings(pcfg, mesh, serve_exact_rules())
     dtype = jnp.float32 if rehearse else jnp.bfloat16
-    params = weights.make(cfg, seed, dtype)
+    params = weights.make(cfg, seed, dtype, placed)
     jax.block_until_ready(params)
     log(f"[bench] weights at {time.perf_counter() - t_process:.1f} s")
     argv = ["--paged", "--telemetry", "--arch", cfg["arch"],
@@ -326,14 +375,17 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if not rehearse:
         argv.append("--published-width")
     args = serve.parse_args(argv)
-    from repro.configs import get_config
-    pcfg = get_config(cfg["arch"], reduced=rehearse)
-    _check_config(cfg, pcfg)
     _check_layout(params, pcfg)
     tel = Telemetry(capacity=1 << 18, record_capacity=1 << 17,
                     percentile_window=1 << 17)
     eng = serve.build_paged_engine(pcfg, params, NLDPEConfig(enabled=False),
-                                   args, max_len=E["max_len"], telemetry=tel)
+                                   args, max_len=E["max_len"], mesh=mesh,
+                                   telemetry=tel)
+    if mesh is not None and any(
+            a is not b for a, b in zip(jax.tree.leaves(params),
+                                       jax.tree.leaves(eng.params))):
+        raise ValueError("the engine moved the benchmark's weights: their "
+                         "placement is not the one the engine gives them")
     if (eng.prefill_chunk, eng.decode_block) != (E["prefill_chunk"],
                                                  E["decode_block"]):
         raise ValueError("engine chunk/block sizes differ from the "
@@ -548,7 +600,9 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
                                           key=lambda kv: -kv[1][0]):
             log(f"[trace] {name}: {secs:.4f} s in {count} executions")
         log(f"[trace] busy {reduced.busy_s:.4f} s of "
-            f"{reduced.window_s:.4f} s")
+            f"{reduced.window_s:.4f} s; collectives "
+            f"{reduced.collective_s:.4f} s, of which exposed "
+            f"{reduced.collective_exposed_s:.4f} s (per chip)")
     if prof_dir:
         shutil.rmtree(prof_dir, ignore_errors=True)
     run = Run(cfg=cfg, window=(t_open, t_close), t_end=t_end,
@@ -557,6 +611,7 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool, *,
               events=events, reduced=reduced, peaks=peaks,
               profile=((profile["start"], profile["end"]) if profile
                        else None),
+              chips=len(devs),
               slots=E["slots"], prefill_chunk=E["prefill_chunk"],
               decode_block=E["decode_block"], requests=by_rid)
 

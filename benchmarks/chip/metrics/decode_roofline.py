@@ -1,8 +1,9 @@
 """Decode step against its memory roofline, in percent: the least bytes a
 step needs (the weights once, plus the live K/V of the active slots at
-their true context lengths) over HBM bandwidth, divided by the measured
-device time per step.  Averaged over the decode ticks whose tokens
-reached the host in the traced stretch."""
+their true context lengths) over the HBM bandwidth of all the cell's
+chips, divided by the measured device time per step.  A weight that
+several chips hold is still counted once.  Averaged over the decode
+ticks whose tokens reached the host in the traced stretch."""
 from benchmarks.chip import model_math
 
 
@@ -12,4 +13,4 @@ def read(run):
     if step_s is None or run.peaks is None or not steps:
         return None
     least = model_math.decode_least_bytes(run.cfg, steps, attended) / steps
-    return 100.0 * least / run.peaks["hbm_bytes_s"] / step_s
+    return 100.0 * least / run.peak("hbm_bytes_s") / step_s

@@ -1,7 +1,7 @@
-"""Useful FLOPs over the window's seconds at the chip's bf16 peak, in
-percent: prompt tokens computed (not those served from the prefix
-cache) with one readout each, and generated tokens with their readout
-and attention over their true context."""
+"""Useful FLOPs over the window's seconds at the bf16 peak of the cell's
+chips, in percent: prompt tokens computed (not those served from the
+prefix cache) with one readout each, and generated tokens with their
+readout and attention over their true context."""
 from benchmarks.chip import model_math
 
 
@@ -13,4 +13,4 @@ def read(run):
     for t, emitted, attended, _ in run.harvests:
         if run.in_window(t):
             flops += model_math.decode_flops(run.cfg, emitted, attended)
-    return 100.0 * flops / run.peaks["bf16_flop_s"] / run.window_s()
+    return 100.0 * flops / run.peak("bf16_flop_s") / run.window_s()

@@ -3,7 +3,8 @@
 It imports nothing of the program.  It follows the configuration file:
 pre-norm decoder layers (RMSNorm, grouped-query attention with rotary
 positions on the two halves of each head, optional q/k/v biases, a SwiGLU
-MLP), a final RMSNorm and a readout tied to the embedding.  It runs one
+MLP), a final RMSNorm and a readout: the embedding's transpose where the
+configuration ties them, else its own ``lm_head`` matrix.  It runs one
 whole sequence at a time, with no cache, no paging and no batching, at
 ``jax.default_matmul_precision("highest")`` so that float32 is float32 on
 the TPU too.  Queries go through in blocks so that no (heads, L, L) score
@@ -122,13 +123,14 @@ def _logit_stats(w, tokens, cand, *, cfg_items, lowp):
     logit, and the argmax, of the pass in the given precision."""
     cfg = dict(cfg_items)
     x = _hidden(w, cfg, tokens, lowp)
-    table = w["embed"]["table"]
+    readout = (w["lm_head"]["w"] if "lm_head" in w
+               else w["embed"]["table"].T)
     nb = x.shape[0] // LOGIT_BLOCK
 
     def block(i):
         xb = jax.lax.dynamic_slice_in_dim(x, i * LOGIT_BLOCK, LOGIT_BLOCK)
         cb = jax.lax.dynamic_slice_in_dim(cand, i * LOGIT_BLOCK, LOGIT_BLOCK)
-        lg = _mm(xb, table.T, lowp)
+        lg = _mm(xb, readout, lowp)
         got = jnp.take_along_axis(lg, cb[:, None], axis=1)[:, 0]
         return jnp.max(lg, -1) - got, jnp.argmax(lg, -1).astype(jnp.int32)
 

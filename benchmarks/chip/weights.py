@@ -3,13 +3,20 @@
 The program under test and the plain reference are both handed these
 arrays, so the reference takes nothing that the program made.  The tree
 follows the layout the program's decoder reads (``embed``, the layer
-stack under ``groups/b0``, ``final_norm``); the harness checks it against
-the program's own parameter shapes before serving.
+stack under ``groups/b0``, ``final_norm``, and ``lm_head`` where the
+readout is not tied to the embedding); the harness checks it against the
+program's own parameter shapes before serving.
 
 Scales keep activations and logits of order one at any width: every
-projection is N(0, 1/fan_in), the tied embedding N(0, 1/hidden) so that a
-logit is N(0, 1), norm scales are 1 + N(0, 0.1) and attention biases
-N(0, 0.1), so that no part of the block is an identity.
+projection is N(0, 1/fan_in), the embedding and an untied readout
+N(0, 1/hidden) so that a logit is N(0, 1), norm scales are 1 + N(0, 0.1)
+and attention biases N(0, 0.1), so that no part of the block is an
+identity.
+
+Each leaf is drawn from its own ``fold_in`` key, by its place in the
+sorted paths (``lm_head`` sorts last, so a tied configuration's values do
+not depend on it), and JAX's partitionable threefry gives the same values
+whatever the output's sharding.
 """
 from __future__ import annotations
 
@@ -50,12 +57,16 @@ def shapes(cfg: dict) -> dict:
                     ("attn", "bv"): ((hkv, hd), "normal", 0.1)})
     for path, (shape, init, scale) in blk.items():
         out[("groups", "b0") + path] = ((n,) + shape, init, scale)
+    if not cfg.get("tie_word_embeddings", True):
+        out[("lm_head", "w")] = ((d, v), "normal", d ** -0.5)
     return out
 
 
-def make(cfg: dict, seed: int, dtype):
-    """All weights in ``dtype``, made on the default device by one jitted
-    call from the seed."""
+def make(cfg: dict, seed: int, dtype, shardings=None):
+    """All weights in ``dtype``, made by one jitted call from the seed: on
+    the default device, or each leaf straight into its sharding in
+    ``shardings`` (a tree of the weights' layout), so that no chip ever
+    holds the whole tree."""
     import jax
     import jax.numpy as jnp
 
@@ -75,4 +86,4 @@ def make(cfg: dict, seed: int, dtype):
         return tree
 
     key = jax.random.wrap_key_data(jnp.asarray(key_words(seed)))
-    return jax.jit(build)(key)
+    return jax.jit(build, out_shardings=shardings)(key)
